@@ -13,8 +13,11 @@ database regardless of how many clients run the mix, because the plan
 cache, the locked compiled-step cache and the single-flight block cache
 all deduplicate it.  Aggregate throughput therefore grows with N even on
 a single core: N clients amortize the same cold work over N times the
-queries.  Each thread-count level runs in a fresh subprocess (fresh XLA
-process cache) so no warm state leaks between levels.
+queries.  Each thread-count level starts with the engine's compiled-step
+cache and JAX's in-memory caches emptied and runs with the persistent
+compile cache off, so no traced or compiled step leaks between levels or
+in from earlier runs.  Everything runs in one process: a chip belongs to
+the process that first touches JAX.
 
 Measured per level N ∈ {1, 2, 4, 8}:
 
@@ -35,19 +38,14 @@ Results land in ``BENCH_concurrent.json`` (cwd) for machine consumption.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 import threading
 import time
-from pathlib import Path
 
 N_ROWS = 400_000
 MEMORY_BUDGET = 256 << 20
 DEVICE_BUDGET = 256 << 20
 THREAD_COUNTS = (1, 2, 4, 8)
 QUERIES_PER_THREAD = 12
-_DEVICES = 4                      # matches the CI concurrent-job topology
 
 
 def _dataset():
@@ -145,8 +143,8 @@ def _pct(sorted_xs, p):
 
 
 def _child(n_threads: int) -> dict:
-    """One measurement level, run in a fresh process: N clients against one
-    cold database, then a serial reference for bit-identity."""
+    """One measurement level: N clients against one cold database, then a
+    serial reference for bit-identity."""
     import numpy as np
 
     from repro.core import startup
@@ -201,39 +199,39 @@ def _child(n_threads: int) -> dict:
     return level
 
 
-def _spawn_level(n_threads: int) -> dict:
-    """Run one level in a fresh interpreter so XLA's in-process caches are
-    cold: each level pays (and amortizes) its own compile + upload work."""
-    root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_DEVICES}"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(root / "src"), str(root)]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_concurrent",
-         "--level", str(n_threads)],
-        cwd=root, env=env, capture_output=True, text=True, timeout=600)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"level {n_threads} failed:\n{proc.stdout}\n{proc.stderr}")
-    for line in reversed(proc.stdout.strip().splitlines()):
-        if line.startswith("{"):
-            return json.loads(line)
-    raise RuntimeError(f"level {n_threads}: no JSON in output:\n"
-                       f"{proc.stdout}\n{proc.stderr}")
+def _cold_level(n_threads: int) -> dict:
+    """Run one level with the compiled-step cache and JAX's in-memory
+    caches emptied and the persistent compile cache off: each level pays
+    (and amortizes) its own tracing, compilation and upload work."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.core import parallel
+    with parallel._STEP_CACHE_LOCK:
+        parallel._STEP_CACHE.clear()
+    jax.clear_caches()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return _child(n_threads)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 def run() -> list[str]:
+    import jax
+
     from .common import row
 
     out_rows: list[str] = []
     res: dict = {"n_rows": N_ROWS, "memory_budget": MEMORY_BUDGET,
-                 "device_budget": DEVICE_BUDGET, "devices": _DEVICES,
+                 "device_budget": DEVICE_BUDGET,
+                 "devices": len(jax.devices()),
                  "queries_per_thread": QUERIES_PER_THREAD, "levels": {}}
     for n in THREAD_COUNTS:
-        level = _spawn_level(n)
+        level = _cold_level(n)
         res["levels"][str(n)] = level
         out_rows.append(row(f"concurrent_n{n}", level["p50_ms"] / 1e3,
                             f"qps={level['qps']:.0f} "
@@ -255,10 +253,6 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
-    if "--level" in sys.argv:
-        n = int(sys.argv[sys.argv.index("--level") + 1])
-        print(json.dumps(_child(n)))
-    else:
-        print("name,us_per_call,derived")
-        for line in run():
-            print(line)
+    print("name,us_per_call,derived")
+    for line in run():
+        print(line)

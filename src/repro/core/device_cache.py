@@ -44,6 +44,7 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Optional
 
 import numpy as np
@@ -73,13 +74,36 @@ def _is_delta_key(key: tuple) -> bool:
     return isinstance(v, tuple) and len(v) >= 3 and v[1] == "d"
 
 
-def _jax():
-    """Lazy jax import.  x64 is forced on exactly as parallel.py does at
-    import: analytical columns are int64/float64 and a silent downcast in
-    ``device_put`` would corrupt them when this module is used before the
-    execution tier was imported."""
+# Persistent XLA compile cache of the device tier when neither
+# JAX_COMPILATION_CACHE_DIR nor the embedding application places one: a
+# fixed directory in the checkout whose src/ tree the package runs from.
+# The path is part of the cache key, so it must never be derived from a
+# temp dir, pid or time.
+DEFAULT_COMPILE_CACHE_DIR = str(
+    Path(__file__).resolve().parents[3] / ".jax_cache")
+
+_JAX_CONFIG_LOCK = threading.Lock()
+_jax_configured = False
+
+
+def jax_runtime():
+    """Import jax configured for the device tier, once per process.
+
+    x64 is forced on: analytical columns are int64/float64 and a silent
+    downcast in ``device_put`` would corrupt them.  The persistent compile
+    cache stays where JAX placed it (``JAX_COMPILATION_CACHE_DIR``, read by
+    JAX at import) or the application did, and otherwise goes to
+    ``DEFAULT_COMPILE_CACHE_DIR``; JAX's default threshold (compiles over
+    one second) decides which steps are kept."""
+    global _jax_configured
     import jax
-    jax.config.update("jax_enable_x64", True)
+    with _JAX_CONFIG_LOCK:
+        if not _jax_configured:
+            jax.config.update("jax_enable_x64", True)
+            if not jax.config.jax_compilation_cache_dir:
+                jax.config.update("jax_compilation_cache_dir",
+                                  DEFAULT_COMPILE_CACHE_DIR)
+            _jax_configured = True
     return jax
 
 
@@ -192,7 +216,7 @@ class DeviceBufferManager:
         next until something forces the value (that is the prefetch
         mechanism).  ``dirty=True`` marks re-uploaded intermediates, whose
         only authoritative copy must follow them back out on eviction."""
-        jax = _jax()
+        jax = jax_runtime()
         arr = np.ascontiguousarray(host_array)
         nbytes = int(arr.nbytes)
         with self._lock:
@@ -389,6 +413,7 @@ class DeviceBufferManager:
 
 
 __all__ = ["DeviceBufferManager", "DeviceBudgetError", "DeviceBlockKeys",
+           "jax_runtime",
            "VALID_PSEUDOCOL", "CARRY_TABLE"]
 
 

@@ -492,6 +492,9 @@ class ExecStats:
                                         # "join-resident", "join-streamed"
     device_sorted: bool = False         # ORDER BY fused onto the device
                                         # assembly (host suffix sort skipped)
+    device_fallback: str = ""           # "ExcClass: message" when a query
+                                        # the planner put on a device tier
+                                        # was recomputed on the host
     device_cache_hits: int = 0          # blocks served without a transfer
     device_prefetch_hits: int = 0       # blocks whose copy was issued ahead
     device_evictions: int = 0           # blocks evicted under budget pressure

@@ -41,31 +41,28 @@ Chunking heuristics follow the paper: the shard count comes from the mesh
 
 from __future__ import annotations
 
+import math
 import threading
 from typing import Optional
 
 import numpy as np
 
-import jax
+from .device_cache import (DeviceBlockKeys, DeviceBudgetError,
+                           DeviceBufferManager, jax_runtime)
 
 # Analytical correctness needs 64-bit aggregation (the paper's engine sums
-# DECIMALs exactly).  Enabling x64 only widens the *available* dtypes; all
-# model-side code in this repo is dtype-explicit, so LM HLO is unaffected.
-jax.config.update("jax_enable_x64", True)
+# DECIMALs exactly); jax_runtime also places the persistent compile cache.
+jax = jax_runtime()
 
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-import math
-
-from .device_cache import (DeviceBlockKeys, DeviceBudgetError,
-                           DeviceBufferManager)
-from .executor import Executor, _res_nulls, compile_plan
-from .expression import EvalContext, Expr, ExprResult
-from .physplan import (AGG_RESULT_NAME, DeviceBuild, JoinAggSpec,
-                       PhysicalPlan, ScanAggSpec,
+from .executor import Executor, _res_nulls, compile_plan  # noqa: E402
+from .expression import EvalContext, Expr, ExprResult  # noqa: E402
+from .physplan import (AGG_RESULT_NAME, DeviceBuild,  # noqa: E402
+                       JoinAggSpec, PhysicalPlan, ScanAggSpec,
                        TIER_DEVICE_RESIDENT, choose_device_join_tier,
-                       choose_device_tier, join_agg_geometry,
+                       choose_device_tier, default_mesh, join_agg_geometry,
                        match_scan_agg,  # noqa: F401  (re-exported for tests)
                        mesh_shards, partial_layout, scan_agg_geometry)
 from .relalg import PlanNode
@@ -206,22 +203,6 @@ def make_fragment(spec: ScanAggSpec, meta: dict, data_axis: str = "data"):
     return fragment
 
 
-def _shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level ``jax.shard_map`` with
-    ``check_vma`` on newer releases, ``jax.experimental.shard_map`` with
-    ``check_rep`` on older ones."""
-    try:
-        from jax import shard_map as sm              # newer jax
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def build_query_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
                      data_axis: str = "data"):
     """jit(shard_map(fragment)) with row-sharded inputs; also used by the
@@ -234,10 +215,10 @@ def build_query_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
         return frag(valid, **arrays)
 
     in_specs = (rowspec,) + tuple(rowspec for _ in spec.columns)
-    f = _shard_map_compat(
+    f = jax.shard_map(
         lambda valid, *cols: merged_axis_fragment(
             valid, **dict(zip(spec.columns, cols))),
-        mesh=mesh, in_specs=in_specs, out_specs=P())
+        mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
     return jax.jit(f)
 
 
@@ -391,8 +372,8 @@ def build_batch_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
             v, full = _gather_expand(gather, inv, valid, cols)
             return frag(v, **dict(zip(spec.columns, full)))
         n_in = 2 + len(spec.columns)
-    sm = _shard_map_compat(shard_fn, mesh=mesh,
-                           in_specs=(rowspec,) * n_in, out_specs=P())
+    sm = jax.shard_map(shard_fn, mesh=mesh, in_specs=(rowspec,) * n_in,
+                       out_specs=P(), check_vma=False)
     kinds = layout.kinds
 
     def step(carry, *args):
@@ -491,10 +472,10 @@ def build_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
                                      args[n_children + 2:])
             return fragment(args[:n_children], v, *full)
         n_rows_in = 2 + len(build.columns)
-    sm = _shard_map_compat(
+    sm = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(),) * n_children + (rowspec,) * n_rows_in,
-        out_specs=P())
+        out_specs=P(), check_vma=False)
 
     def step(btab, *args):
         return btab + sm(*args)
@@ -561,10 +542,10 @@ def build_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
                                      args[n_children + 2:])
             return fragment(args[:n_children], v, *full)
         n_rows_in = 2 + len(pspec.columns)
-    sm = _shard_map_compat(
+    sm = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(),) * n_children + (rowspec,) * n_rows_in,
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     kinds = layout.kinds
 
     def step(carry, *args):
@@ -1325,18 +1306,20 @@ class _SuffixDatabase:
 class ParallelExecutor(Executor):
     """Routes qualifying plans to the shard_map tier (paper Fig. 2)."""
 
-    def __init__(self, database, mesh: Optional[Mesh] = None,
-                 use_pallas: bool = False):
+    def __init__(self, database, mesh: Optional[Mesh] = None):
         super().__init__(database)
         self.mesh = mesh
-        self.use_pallas = use_pallas
-        self.distributed_hits = 0
 
     def _default_mesh(self) -> Mesh:
         if self.mesh is None:
-            dev = np.array(jax.devices())
-            self.mesh = Mesh(dev.reshape(-1), ("data",))
+            self.mesh = default_mesh()
         return self.mesh
+
+    def _fall_back(self, exc: BaseException) -> None:
+        """Record why a device attempt is abandoned for a host recompute
+        (``ExecStats.device_fallback``); returns None, the caller's
+        fall-back signal."""
+        self.stats.device_fallback = f"{type(exc).__name__}: {exc}"
 
     def execute(self, plan: PlanNode, do_optimize: bool = True):
         from .serving import lower_cached
@@ -1354,8 +1337,9 @@ class ParallelExecutor(Executor):
                     return result
                 # the planner chose the device tier but runtime lowering
                 # failed; the host program is the fallback — re-render so
-                # EXPLAIN/stats reflect what actually ran
-                phys.demote_device()
+                # EXPLAIN/stats reflect what actually ran, and why
+                phys.demote_device(
+                    self.stats.device_fallback.splitlines()[0])
                 self.stats.plan_repr = phys.render()
             prog = compile_plan(phys.plan, self.db.catalog)
             result = self.run_program(prog)
@@ -1374,8 +1358,7 @@ class ParallelExecutor(Executor):
                       device_sorted: bool) -> None:
         # claim the device tier only once the WHOLE query succeeded: a
         # suffix failure falls back to a full host recompute, and
-        # device_tier / distributed_hits must describe the result returned
-        self.distributed_hits += 1
+        # device_tier must describe the result returned
         self.stats.device_tier = tier
         self.stats.device_sorted = device_sorted
         for f, b, e in zip(fields, base, end):
@@ -1402,8 +1385,8 @@ class ParallelExecutor(Executor):
                 self.db, spec, self._default_mesh(),
                 batch_rows=getattr(self.db, "device_batch_rows", None),
                 skip_set=phys.core_skip_set())
-        except Exception:
-            return None
+        except Exception as e:
+            return self._fall_back(e)
         tier = "resident" if phys.agg_tier == TIER_DEVICE_RESIDENT \
             else "streamed"
         fields, stats_base = self._stats_window()
@@ -1418,8 +1401,8 @@ class ParallelExecutor(Executor):
                     spec, sort_cols, phys.sort_node.limit, 0)
         try:
             out = agg.run(tier, assemble=assemble)
-        except Exception:
-            return None      # fall back to the host tier on any lowering gap
+        except Exception as e:
+            return self._fall_back(e)   # any lowering gap: host tier
         if agg.delta_rows:
             # merge-on-read visibility: the scan consumed a delta tail
             agg.devman.bump(delta_rows=agg.delta_rows)
@@ -1434,8 +1417,9 @@ class ParallelExecutor(Executor):
         if phys.suffix_plan is not None and assemble is None:
             try:
                 result = self._run_suffix(phys.suffix_plan, result)
-            except Exception:
-                return None  # suffix gap: host program recomputes everything
+            except Exception as e:
+                # suffix gap: the host program recomputes everything
+                return self._fall_back(e)
         self._claim_device(tier, fields, base, end, dm,
                            device_sorted=assemble is not None)
         return result
@@ -1452,8 +1436,8 @@ class ParallelExecutor(Executor):
                 self.db, jspec, self._default_mesh(),
                 batch_rows=getattr(self.db, "device_batch_rows", None),
                 skip_sets={t: phys.skip_set_for_table(t) for t in tables})
-        except Exception:
-            return None
+        except Exception as e:
+            return self._fall_back(e)
         mode = phys.join_mode or "streamed"
         fields, stats_base = self._stats_window()
         dm = agg.devman.stats
@@ -1472,10 +1456,10 @@ class ParallelExecutor(Executor):
                                          n_payload)
         try:
             gids, vals, pay = agg.run(mode, assemble=assemble)
-        except _DeviceJoinFallback:
-            return None     # duplicate build keys: host join is the truth
-        except Exception:
-            return None     # fall back to the host tier on any lowering gap
+        except Exception as e:
+            # _DeviceJoinFallback (duplicate build keys: the host join is
+            # the truth) or any lowering gap
+            return self._fall_back(e)
         if agg.delta_rows:
             agg.devman.bump(delta_rows=agg.delta_rows)
         result = self._assemble_join(jspec, gids, vals, pay)
@@ -1483,8 +1467,8 @@ class ParallelExecutor(Executor):
         if phys.suffix_plan is not None and not device_sorted:
             try:
                 result = self._run_suffix(phys.suffix_plan, result)
-            except Exception:
-                return None
+            except Exception as e:
+                return self._fall_back(e)
         self._claim_device("join-" + mode, fields, base, end, dm,
                            device_sorted=device_sorted)
         return result

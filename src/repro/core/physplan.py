@@ -632,6 +632,15 @@ def choose_device_join_tier(resident_bytes: float, working_bytes: float,
     return "resident"
 
 
+def default_mesh():
+    """The device tier's mesh when the caller passes none: every device JAX
+    reports, on one ``data`` axis."""
+    from jax.sharding import Mesh
+
+    from .device_cache import jax_runtime
+    return Mesh(np.array(jax_runtime().devices()).reshape(-1), ("data",))
+
+
 def mesh_shards(mesh) -> int:
     shards = 1
     for ax in mesh.axis_names:
@@ -1388,9 +1397,7 @@ def plan_physical(plan: PlanNode, db, *, do_optimize: bool = True,
     if shard_table.num_rows < MIN_ROWS_TO_SHARD:
         return phys
     if mesh is None:
-        import jax
-        from jax.sharding import Mesh
-        mesh = Mesh(np.array(jax.devices()).reshape(-1), ("data",))
+        mesh = default_mesh()
     shards = mesh_shards(mesh)
     batch_rows = getattr(db, "device_batch_rows", None)
     if spec is not None:

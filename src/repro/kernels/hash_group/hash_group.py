@@ -42,7 +42,7 @@ def _hash_group_kernel(gid_ref, vals_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("g_pad", "block_rows",
                                              "interpret"))
 def hash_group_call(gid: jax.Array, vals: jax.Array, g_pad: int, *,
-                    block_rows: int = 2048, interpret: bool = True):
+                    block_rows: int = 2048, interpret: bool = False):
     """gid: (1, n) int32 — masked-out rows carry a trash group id that lands
     in a padding row (callers use g_pad - 1); vals: (V, n) f32 with V padded
     to the f32 sublane multiple.  g_pad is the padded group-domain size.

@@ -20,7 +20,7 @@ def _pad8(x: int) -> int:
 
 def grouped_aggregate(gid: np.ndarray, vals: np.ndarray, n_groups: int,
                       mask: np.ndarray | None = None,
-                      block_rows: int = 2048, interpret: bool = True,
+                      block_rows: int = 2048, interpret: bool = False,
                       use_pallas: bool = True) -> np.ndarray:
     """gid: (n,) int; vals: (V, n) float; returns (n_groups, V+1) float64 —
     per-group sums for each value column plus the group count in the last

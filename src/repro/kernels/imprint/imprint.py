@@ -47,7 +47,7 @@ def _zone_kernel(nbins: int, vals_ref, valid_ref, rng_ref,
                                              "interpret"))
 def zone_maps_pallas(vals: jax.Array, valid: jax.Array, rng: jax.Array,
                      *, block_rows: int = 2048, nbins: int = 16,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """vals/valid: (n_blocks, block_rows) f32 (pre-padded); rng: (1, 2) f32
     holding (lo, nbins/(hi-lo)).  Returns (mins, maxs, bitmaps)."""
     n_blocks = vals.shape[0]

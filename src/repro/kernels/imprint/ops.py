@@ -66,7 +66,7 @@ def build_zone_maps(values: np.ndarray, nulls: np.ndarray,
 
 
 def build_zone_maps_pallas(values: np.ndarray, nulls: np.ndarray,
-                           block: int, nbins: int, interpret: bool = True):
+                           block: int, nbins: int, interpret: bool = False):
     """Device-tier zone maps through the Pallas kernel.  Same contract as
     build_zone_maps (float32 bounds; callers widen conservatively)."""
     import jax.numpy as jnp

@@ -27,7 +27,7 @@ def _pad_to(n: int, block: int) -> int:
 
 def radix_join(build_keys: np.ndarray, build_vals: np.ndarray,
                probe_keys: np.ndarray, *, n_bits: int = 4,
-               block_rows: int = 2048, interpret: bool = True,
+               block_rows: int = 2048, interpret: bool = False,
                use_pallas: bool = True) -> tuple[np.ndarray, np.ndarray]:
     """build_keys: (nb,) int, unique; build_vals: (V, nb) float;
     probe_keys: (np,) int.  Returns ``(matched, gathered)`` where

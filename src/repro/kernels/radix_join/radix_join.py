@@ -66,7 +66,7 @@ def _radix_probe_kernel(code_ref, btab_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("d_pad", "block_rows",
                                              "interpret"))
 def radix_build_call(code: jax.Array, vals: jax.Array, d_pad: int, *,
-                     block_rows: int = 2048, interpret: bool = True):
+                     block_rows: int = 2048, interpret: bool = False):
     """code: (1, n) int32 partition-local key codes — masked-out rows carry
     a trash code that lands in a padding row (callers use d_pad - 1); vals:
     (V, n) f32 payload with V padded to the f32 sublane multiple and lane 0
@@ -91,7 +91,7 @@ def radix_build_call(code: jax.Array, vals: jax.Array, d_pad: int, *,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def radix_probe_call(code: jax.Array, btab: jax.Array, *,
-                     block_rows: int = 2048, interpret: bool = True):
+                     block_rows: int = 2048, interpret: bool = False):
     """code: (1, n) int32 partition-local probe codes (trash code = the
     padding row, whose presence count is 0, so padded probes simply miss);
     btab: (D, V) f32 build table from ``radix_build_call``.  Returns the

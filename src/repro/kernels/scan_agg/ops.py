@@ -18,7 +18,7 @@ _WIDE = np.float32(3.0e38)
 
 def fused_filter_agg(cols: np.ndarray, ranges: np.ndarray,
                      pairs: tuple[tuple[int, int], ...],
-                     block_rows: int = 8192, interpret: bool = True,
+                     block_rows: int = 8192, interpret: bool = False,
                      use_pallas: bool = True) -> np.ndarray:
     """cols: (C, n) float; ranges: (C, 2); returns (P+1,) float64 —
     one sum per pair plus the selected count.

@@ -54,7 +54,7 @@ def _scan_agg_kernel(pairs, cols_ref, ranges_ref, out_ref):
                    static_argnames=("pairs", "block_rows", "interpret"))
 def scan_agg_pallas(cols: jax.Array, ranges: jax.Array, *,
                     pairs: tuple[tuple[int, int], ...],
-                    block_rows: int = 8192, interpret: bool = True):
+                    block_rows: int = 8192, interpret: bool = False):
     """cols: (C, n) f32 with n % block_rows == 0 and C % 8 == 0 (pre-padded,
     padding rows carry values outside every range).  Returns (n_steps, 128)
     partials."""

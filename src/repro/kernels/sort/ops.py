@@ -29,7 +29,7 @@ def _next_pow2(n: int) -> int:
     return p
 
 
-def sort_block(keys: np.ndarray, *, interpret: bool = True,
+def sort_block(keys: np.ndarray, *, interpret: bool = False,
                use_pallas: bool = True) -> tuple[np.ndarray, np.ndarray]:
     """keys: (n,) float.  Returns ``(sorted, perm)`` ascending with NaNs
     last; ``perm`` is the stable argsort permutation."""

@@ -61,7 +61,7 @@ def _bitonic_kernel(keys_ref, idx_ref, out_k_ref, out_i_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bitonic_sort_call(keys: jax.Array, idx: jax.Array, *,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """keys: (1, n) f32 with n a power of two (callers pad with +inf);
     idx: (1, n) int32 original positions.  Returns (sorted keys, perm),
     ascending, ties broken by original position (= stable)."""

@@ -268,6 +268,40 @@ def test_budget_validation():
         DeviceBufferManager(budget=-1)
 
 
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The persistent compile cache honours JAX_COMPILATION_CACHE_DIR and
+    otherwise sits at one fixed path inside the checkout, so every process
+    of this checkout finds the same entries."""
+    import os
+    import subprocess
+    import sys
+
+    import jax
+
+    from repro.core import device_cache as dc
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # JAX reads the variable at import: check it in a fresh interpreter
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH=os.path.join(checkout, "src"))
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from repro.core.device_cache import jax_runtime; "
+         "print(jax_runtime().config.jax_compilation_cache_dir)"],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    assert out.stdout.strip() == str(tmp_path)
+    # unset: two first calls place the same fixed in-checkout path
+    was = jax.config.jax_compilation_cache_dir
+    placed = []
+    try:
+        for _ in range(2):
+            jax.config.update("jax_compilation_cache_dir", None)
+            monkeypatch.setattr(dc, "_jax_configured", False)
+            placed.append(dc.jax_runtime().config.jax_compilation_cache_dir)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert placed == [os.path.join(checkout, ".jax_cache")] * 2
+
+
 def test_carry_eviction_mid_query_reuploads(lineitem, monkeypatch):
     """Force the merge carry (the only dirty block a query owns) out of the
     cache after every batch: the streaming loop must write it back, re-

@@ -235,6 +235,46 @@ def test_duplicate_build_keys_fall_back_to_host_join(host_q3):
         host.shutdown()
 
 
+@pytest.mark.parametrize("case", ["clean", "duplicate_keys",
+                                  "lowering_error"])
+def test_device_fallback_reason(monkeypatch, case):
+    """``ExecStats.device_fallback`` says why a device-planned query was
+    recomputed on the host: empty on a clean device run, the declared
+    fallback's reason for duplicate build keys, and the exception class
+    of any other failure (here a step the compiler refuses).  The host
+    answer is returned either way."""
+    from repro.core import parallel as par
+    keys = np.arange(200)
+    if case == "duplicate_keys":
+        keys = np.concatenate([keys, [7]])
+    dim = {"k": keys.astype(np.int64), "grp": (keys % 5).astype(np.int64)}
+    if case == "lowering_error":
+        def refused(*a, **kw):
+            raise NotImplementedError("step refused by the compiler")
+        monkeypatch.setattr(par, "_cached_join_probe_step", refused)
+    dev = startup(device_budget=64 << 20, device_batch_rows=BATCH_ROWS)
+    host = startup()
+    try:
+        got = _star(dev, dim).execute(distributed=True).to_pydict()
+        st = dev.last_stats
+        _assert_matches(got, _star(host, dim).execute().to_pydict(), case,
+                        exact=False)
+    finally:
+        dev.shutdown()
+        host.shutdown()
+    if case == "clean":
+        assert st.device_tier == "join-resident"
+        assert st.device_fallback == ""
+        return
+    want = {"duplicate_keys": "_DeviceJoinFallback: duplicate join keys "
+                              "in build table dim",
+            "lowering_error": "NotImplementedError: step refused by the "
+                              "compiler"}[case]
+    assert st.device_tier == ""
+    assert st.device_fallback == want
+    assert f"join-agg core kept on host ({want})" in st.plan_repr
+
+
 def test_null_probe_keys_never_match():
     """NULL fact keys are sentinel-coded; the probe mask must reject them
     (an inner join drops NULL keys) — differential vs the host join."""

@@ -320,7 +320,9 @@ def test_demoted_core_renders_host_annotation():
     st = db.last_stats
     assert st.device_tier == "", "host recompute must not claim the device"
     assert "(fused)" not in st.plan_repr
-    assert "scan-agg core kept on host (runtime fallback)" in st.plan_repr
+    assert st.device_fallback == "RuntimeError: suffix gap"
+    assert "scan-agg core kept on host (RuntimeError: suffix gap)" \
+        in st.plan_repr
     _assert_bits(ref, out, "demoted")
 
 
