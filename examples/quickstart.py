@@ -253,10 +253,10 @@ star.shutdown()
 # candidate ranges.  Skipping is sound by construction (candidate sets are
 # supersets — proven by a hypothesis property test), version-revalidated
 # at execution, and bit-identical on vs off: pass data_skipping=False to
-# force it off.  On clustered data a selective filter moves proportionally
-# fewer bytes (benchmarks/bench_skipping.py: 8x fewer h2d bytes at 1%
-# selectivity).  EXPLAIN shows the planning-time decision as
-# `(skip: k/N blocks)` on the scan, and the counters land in
+# force it off.  On clustered data a selective filter reads and moves
+# proportionally fewer bytes (the example below counts them).  EXPLAIN
+# shows the planning-time decision as `(skip: k/N blocks)` on the scan,
+# and the counters land in
 # BufferStats/ExecStats: blocks_skipped, bytes_skipped_h2d,
 # bytes_skipped_spill.
 clustered = startup()

@@ -87,7 +87,7 @@ CONTEXT_ACQUIRES = {"pinned", "query_scope", "admit"}
 # inspection (`handle.lower(...)`) does not execute and is not dispatch.
 # ---------------------------------------------------------------------------
 
-DISPATCH_PRODUCERS = {"_cached_batch_step", "_cached_query_step",
+DISPATCH_PRODUCERS = {"_cached_batch_step",
                       "build_batch_step", "build_query_step",
                       "_cached_join_build_step", "_cached_join_probe_step",
                       "build_join_build_step", "build_join_probe_step",

@@ -18,8 +18,8 @@ Three transfer paths between the engine and the embedding analytical code:
 Header forgery has no TPU-side analogue to forge (DESIGN.md §3): a
 ``jax.Array``/numpy view already separates the header object from the
 buffer, so metadata prepending is free; the invariant we keep from the
-paper is *O(1) transfer cost, independent of data size* — asserted in
-benchmarks/bench_export.py.
+paper is *O(1) transfer cost, independent of data size*: a zero-copy
+transfer shares the column's buffer (tests/test_exchange.py).
 """
 
 from __future__ import annotations

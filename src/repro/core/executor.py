@@ -10,7 +10,7 @@ from cardinalities and available indexes.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Optional
 
 import numpy as np
@@ -22,6 +22,7 @@ from .optimizer import split_conjuncts
 from .physplan import TierPolicy, _simple_range
 from .relalg import (AggregateNode, FilterNode, JoinNode, LimitNode,
                      OrderByNode, PlanNode, ProjectNode, ScanNode)
+from .tracing import span
 from .types import DBType, NULL_SENTINEL, STORAGE_DTYPE, is_float
 
 # ---------------------------------------------------------------------------
@@ -504,6 +505,15 @@ class ExecStats:
     # serving layer (serving.py): per-query view of the concurrent path
     plan_cache_hit: bool = False        # lowering skipped via the plan cache
     admission_wait_ms: float = 0.0      # time queued at the admission gate
+    # spans (tracing.py): this query's totals per span name — milliseconds
+    # and times closed — of parse, plan, admit, prepare, device_lock,
+    # loop, step, h2d, fence, assemble, suffix, compile and the query
+    span_ms: dict = field(default_factory=dict)
+    span_n: dict = field(default_factory=dict)
+    device_lock_wait_ms: float = 0.0    # wait for the device dispatch lock
+    programs_built: int = 0             # device programs this query built
+                                        # (each compiles at its first call)
+    query_id: int = 0                   # the ``query`` of its mdb.query span
     reserved_bytes: int = 0             # host reservation the gate granted
     reserved_device_bytes: int = 0      # device reservation granted
     shared_scan_attaches: int = 0       # blocks served by another query's
@@ -588,7 +598,8 @@ class Executor:
             import contextlib
             return contextlib.nullcontext()
         host, device = phys.total_reservations()
-        ticket = gate.admit(host, device)
+        with span("admit", self.stats):
+            ticket = gate.admit(host, device)
         self.stats.admission_wait_ms = ticket.waited * 1000.0
         self.stats.reserved_bytes = ticket.host_bytes
         self.stats.reserved_device_bytes = ticket.device_bytes
@@ -608,8 +619,9 @@ class Executor:
 
     def execute(self, plan: PlanNode, do_optimize: bool = True):
         from .serving import lower_cached
-        phys, rendered, hit = lower_cached(self.db, plan,
-                                           do_optimize=do_optimize)
+        with span("plan", self.stats):
+            phys, rendered, hit = lower_cached(self.db, plan,
+                                               do_optimize=do_optimize)
         self.policy = phys.policy
         self.stats.plan_repr = rendered
         self.stats.plan_cache_hit = hit

@@ -57,7 +57,8 @@ jax = jax_runtime()
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from .executor import Executor, _res_nulls, compile_plan  # noqa: E402
+from .executor import (ExecStats, Executor, _res_nulls,  # noqa: E402
+                       compile_plan)
 from .expression import EvalContext, Expr, ExprResult  # noqa: E402
 from .physplan import (AGG_RESULT_NAME, DeviceBuild,  # noqa: E402
                        JoinAggSpec, PhysicalPlan, ScanAggSpec,
@@ -66,6 +67,7 @@ from .physplan import (AGG_RESULT_NAME, DeviceBuild,  # noqa: E402
                        match_scan_agg,  # noqa: F401  (re-exported for tests)
                        mesh_shards, partial_layout, scan_agg_geometry)
 from .relalg import PlanNode
+from .tracing import span
 from .types import DBType, NULL_SENTINEL
 
 # The scan-agg pattern matcher, the partial-matrix layout, the batch
@@ -215,11 +217,15 @@ def build_query_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
         return frag(valid, **arrays)
 
     in_specs = (rowspec,) + tuple(rowspec for _ in spec.columns)
-    f = jax.shard_map(
+    sm = jax.shard_map(
         lambda valid, *cols: merged_axis_fragment(
             valid, **dict(zip(spec.columns, cols))),
         mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
-    return jax.jit(f)
+
+    def scan_agg_query(valid, *cols):
+        return sm(valid, *cols)
+
+    return jax.jit(scan_agg_query)
 
 
 _STEP_CACHE: dict = {}
@@ -253,20 +259,40 @@ def _meta_key(columns, meta: dict) -> tuple:
     return tuple(out)
 
 
-def _cached_query_step(spec: ScanAggSpec, meta: dict, mesh: Mesh, pad: int):
-    """Compiled-fragment cache: repeated queries (the hot-run benchmark
-    protocol, dashboards) reuse the jitted shard_map step instead of
-    re-tracing per call."""
-    key = (spec.table, repr(spec.conjuncts), tuple(spec.group_keys),
-           tuple(spec.key_domains),     # baked into the trace as constants
-           tuple((a.fn, repr(a.expr)) for a in spec.aggs),
-           _meta_key(spec.columns, meta), spec.n_groups, pad,
-           id(mesh.devices.flat[0]),
-           tuple(mesh.shape.items()))
+def _cached_program(key: tuple, build, stats):
+    """The step cache's entry under ``key`` (one jitted program or a
+    tuple of them), made by ``build()`` on a miss.  Repeated queries reuse
+    the jitted programs instead of re-tracing per call.  A program built
+    here compiles at its first call: the query that built it counts the
+    miss (``ExecStats.programs_built``) and makes that call in a compile
+    span."""
     with _STEP_CACHE_LOCK:
-        if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = build_query_step(spec, meta, mesh)
-        return _STEP_CACHE[key]
+        progs = _STEP_CACHE.get(key)
+        built = progs is None
+        if built:
+            progs = _STEP_CACHE[key] = build()
+    if not built or stats is None:
+        return progs
+    stats.programs_built += 1
+    if isinstance(progs, tuple):
+        return tuple(_compile_span(p, stats) for p in progs)
+    return _compile_span(progs, stats)
+
+
+def _compile_span(fn, stats):
+    """``fn`` whose first call, the one that traces and compiles it, runs
+    in a compile span."""
+    compiled = False
+
+    def call(*args):
+        nonlocal compiled
+        if compiled:
+            return fn(*args)
+        compiled = True
+        with span("compile", stats):
+            return fn(*args)
+
+    return call
 
 
 # ---------------------------------------------------------------------------
@@ -376,22 +402,25 @@ def build_batch_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
                        out_specs=P(), check_vma=False)
     kinds = layout.kinds
 
-    def step(carry, *args):
+    def scan_agg_step(carry, *args):
         part = sm(*args)
         return jnp.where(kinds == 0, carry + part,
                          jnp.where(kinds == 1, jnp.minimum(carry, part),
                                    jnp.maximum(carry, part)))
 
-    rep_sh = NamedSharding(mesh, P())
     g, k = spec.n_groups, len(kinds)
-    init = jax.jit(lambda: jnp.broadcast_to(
-        jnp.asarray(layout.init), (g, k)) + jnp.float64(0.0),
-        out_shardings=rep_sh)
-    return init, jax.jit(step, out_shardings=rep_sh)
+
+    def scan_agg_init():
+        return jnp.broadcast_to(jnp.asarray(layout.init), (g, k)) \
+            + jnp.float64(0.0)
+
+    rep_sh = NamedSharding(mesh, P())
+    return (jax.jit(scan_agg_init, out_shardings=rep_sh),
+            jax.jit(scan_agg_step, out_shardings=rep_sh))
 
 
 def _cached_batch_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
-                       batch_rows: int, gather=None):
+                       batch_rows: int, gather=None, stats=None):
     key = ("batch", spec.table, repr(spec.conjuncts),
            tuple(spec.group_keys),
            tuple(spec.key_domains),     # baked into the trace as constants:
@@ -403,11 +432,9 @@ def _cached_batch_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
            spec.n_groups, batch_rows, gather,
            id(mesh.devices.flat[0]),
            tuple(mesh.shape.items()))
-    with _STEP_CACHE_LOCK:
-        if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = build_batch_step(spec, meta, mesh,
-                                                gather=gather)
-        return _STEP_CACHE[key]
+    return _cached_program(
+        key, lambda: build_batch_step(spec, meta, mesh, gather=gather),
+        stats)
 
 
 # ---------------------------------------------------------------------------
@@ -477,27 +504,29 @@ def build_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
         in_specs=(P(),) * n_children + (rowspec,) * n_rows_in,
         out_specs=P(), check_vma=False)
 
-    def step(btab, *args):
+    def join_build_step(btab, *args):
         return btab + sm(*args)
 
+    def join_build_init():
+        return jnp.zeros((card, width), dtype=jnp.float64) + jnp.float64(0.0)
+
     rep_sh = NamedSharding(mesh, P())
-    init = jax.jit(lambda: jnp.zeros((card, width), dtype=jnp.float64)
-                   + jnp.float64(0.0), out_shardings=rep_sh)
-    return init, jax.jit(step, out_shardings=rep_sh)
+    return (jax.jit(join_build_init, out_shardings=rep_sh),
+            jax.jit(join_build_step, out_shardings=rep_sh))
 
 
 def _cached_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
-                            batch_rows: int, child_domains, gather=None):
+                            batch_rows: int, child_domains, gather=None,
+                            stats=None):
     key = ("jbuild", build.table, repr(build.conjuncts), build.key,
            build.domain, tuple(build.payload), tuple(build.probe_edges),
            tuple(child_domains), _meta_key(build.columns, meta),
            batch_rows, gather, id(mesh.devices.flat[0]),
            tuple(mesh.shape.items()))
-    with _STEP_CACHE_LOCK:
-        if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = build_join_build_step(
-                build, meta, mesh, child_domains, gather=gather)
-        return _STEP_CACHE[key]
+    return _cached_program(
+        key, lambda: build_join_build_step(build, meta, mesh, child_domains,
+                                           gather=gather),
+        stats)
 
 
 def build_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
@@ -548,22 +577,25 @@ def build_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
         out_specs=P(), check_vma=False)
     kinds = layout.kinds
 
-    def step(carry, *args):
+    def join_probe_step(carry, *args):
         part = sm(*args)
         return jnp.where(kinds == 0, carry + part,
                          jnp.where(kinds == 1, jnp.minimum(carry, part),
                                    jnp.maximum(carry, part)))
 
-    rep_sh = NamedSharding(mesh, P())
     g, k = pspec.n_groups, len(kinds)
-    init = jax.jit(lambda: jnp.broadcast_to(
-        jnp.asarray(layout.init), (g, k)) + jnp.float64(0.0),
-        out_shardings=rep_sh)
-    return init, jax.jit(step, out_shardings=rep_sh)
+
+    def join_probe_init():
+        return jnp.broadcast_to(jnp.asarray(layout.init), (g, k)) \
+            + jnp.float64(0.0)
+
+    rep_sh = NamedSharding(mesh, P())
+    return (jax.jit(join_probe_init, out_shardings=rep_sh),
+            jax.jit(join_probe_step, out_shardings=rep_sh))
 
 
 def _cached_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
-                            batch_rows: int, gather=None):
+                            batch_rows: int, gather=None, stats=None):
     pspec = spec.probe_spec()
     key = ("jprobe", spec.probe_table, repr(pspec.conjuncts),
            tuple(pspec.group_keys), tuple(pspec.key_domains),
@@ -573,11 +605,9 @@ def _cached_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
            _meta_key(pspec.columns, meta), pspec.n_groups,
            batch_rows, gather, id(mesh.devices.flat[0]),
            tuple(mesh.shape.items()))
-    with _STEP_CACHE_LOCK:
-        if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = build_join_probe_step(spec, meta, mesh,
-                                                     gather=gather)
-        return _STEP_CACHE[key]
+    return _cached_program(
+        key, lambda: build_join_probe_step(spec, meta, mesh, gather=gather),
+        stats)
 
 
 def build_scalar_step(kind: str):
@@ -586,17 +616,18 @@ def build_scalar_step(kind: str):
     the exact-size compaction trace); ``"dupmax"`` is the max presence
     count of a build matrix — the uniqueness verification the device join
     tier's soundness rests on."""
-    if kind == "present":
-        return jax.jit(lambda m: jnp.sum(m[:, 0] > 0))
-    return jax.jit(lambda m: jnp.max(m[:, 0]))
+    def scalar_present(m):
+        return jnp.sum(m[:, 0] > 0)
+
+    def scalar_dupmax(m):
+        return jnp.max(m[:, 0])
+
+    return jax.jit(scalar_present if kind == "present" else scalar_dupmax)
 
 
-def _cached_scalar_step(kind: str):
-    key = ("scalar", kind)
-    with _STEP_CACHE_LOCK:
-        if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = build_scalar_step(kind)
-        return _STEP_CACHE[key]
+def _cached_scalar_step(kind: str, stats=None):
+    return _cached_program(("scalar", kind),
+                           lambda: build_scalar_step(kind), stats)
 
 
 def _finalize_rows_jnp(spec: ScanAggSpec, carry):
@@ -697,34 +728,38 @@ def build_assemble_step(spec: ScanAggSpec, n_present: int, sort_cols,
 
 
 def _cached_assemble_step(spec: ScanAggSpec, n_present: int, sort_cols,
-                          limit, n_payload: int, mesh: Mesh):
+                          limit, n_payload: int, mesh: Mesh, stats=None):
     key = ("assemble", spec.table, tuple(spec.group_keys),
            tuple(spec.key_domains),
            tuple((a.fn, repr(a.expr)) for a in spec.aggs),
            spec.n_groups, n_present, sort_cols, limit, n_payload,
            id(mesh.devices.flat[0]), tuple(mesh.shape.items()))
-    with _STEP_CACHE_LOCK:
-        if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = build_assemble_step(
-                spec, n_present, sort_cols, limit, n_payload)
-        return _STEP_CACHE[key]
+    return _cached_program(
+        key, lambda: build_assemble_step(spec, n_present, sort_cols, limit,
+                                         n_payload),
+        stats)
 
 
 # requires-lock: _DEVICE_DISPATCH_LOCK
-def _assemble_on_device(plan: tuple, mesh: Mesh, carry, btab=None):
+def _assemble_on_device(plan: tuple, mesh: Mesh, stats, carry, btab=None):
     """Device-resident assembly dispatch: count the present groups (the
     exact-size key of the compaction trace), run the finalize / compact /
     payload-gather / fused-sort step, fetch only the surviving rows.
     ``plan`` is ``(pspec, sort_cols, limit, n_payload)`` — data, not a
     closure, so the dispatch stays inside the lock-annotated call
-    graph."""
+    graph.  The count is the first value the host waits for after the
+    batch loop: its fetch, like the rows', is a fence span."""
     pspec, sort_cols, limit, n_payload = plan
-    present_fn = _cached_scalar_step("present")
-    n_present = int(present_fn(carry))
-    fn = _cached_assemble_step(pspec, n_present, tuple(sort_cols), limit,
-                               n_payload, mesh)
-    gids, vals, pay = fn(carry) if btab is None else fn(carry, btab)
-    return np.asarray(gids), np.asarray(vals), np.asarray(pay)
+    with span("assemble", stats):
+        present_fn = _cached_scalar_step("present", stats)
+        present = present_fn(carry)
+        with span("fence", stats):
+            n_present = int(present)
+        fn = _cached_assemble_step(pspec, n_present, tuple(sort_cols), limit,
+                                   n_payload, mesh, stats)
+        gids, vals, pay = fn(carry) if btab is None else fn(carry, btab)
+        with span("fence", stats):
+            return np.asarray(gids), np.asarray(vals), np.asarray(pay)
 
 
 class _DeviceJoinFallback(Exception):
@@ -756,10 +791,13 @@ class DistributedScanAgg:
     clean by definition."""
 
     def __init__(self, db, spec: ScanAggSpec, mesh: Mesh,
-                 batch_rows: Optional[int] = None, skip_set=None):
+                 batch_rows: Optional[int] = None, skip_set=None,
+                 stats: Optional[ExecStats] = None):
         self.db = db
         self.spec = spec
         self.mesh = mesh
+        # the query's stats: its spans and counters land here
+        self.stats = ExecStats() if stats is None else stats
         self.devman: DeviceBufferManager = getattr(
             db, "device_manager", None) or DeviceBufferManager(
                 stats=getattr(db, "buffer_manager", None).stats
@@ -980,19 +1018,27 @@ class DistributedScanAgg:
         overlap the current batch's compute.  ``put`` recycles the budget
         by evicting *unpinned* (already-consumed) blocks, and the loop
         stops issuing the moment room would require touching a pinned one
-        — double-buffering never breaks ``device_bytes_peak <= budget``."""
-        for key, build in self._builders(b):
-            if key in self.devman or key in prefetched:
-                continue       # cached: will be a cache hit at consumption
-            try:
-                # single-flight even here: two streamed queries walking the
-                # same table prefetch the same next batch — one upload,
-                # the other attaches (and still takes its own pin)
-                self.devman.get_or_put(key, build, sharding=sh, pin=True)
-            except DeviceBudgetError:
-                return
-            prefetched.add(key)
-            query_keys.add(key)
+        — double-buffering never breaks ``device_bytes_peak <= budget``.
+        The batch's builds and copies are one h2d span, opened at the
+        first block that is not cached."""
+        upload = span("h2d", self.stats)
+        try:
+            for key, build in self._builders(b):
+                if key in self.devman or key in prefetched:
+                    continue   # cached: will be a cache hit at consumption
+                upload.open()
+                try:
+                    # single-flight even here: two streamed queries walking
+                    # the same table prefetch the same next batch — one
+                    # upload, the other attaches (and still takes its pin)
+                    self.devman.get_or_put(key, build, sharding=sh,
+                                           pin=True)
+                except DeviceBudgetError:
+                    return
+                prefetched.add(key)
+                query_keys.add(key)
+        finally:
+            upload.close()
 
     def _account_skipping(self) -> None:
         """Bump what the zone maps saved: every block of every whole
@@ -1029,28 +1075,37 @@ class DistributedScanAgg:
         (so double-buffering can never evict it), dispatches its step,
         and resumes the generator, which unpins the consumed batch.
         Shared by the scan-agg carry loop and the join tier's
-        build/probe streams."""
+        build/probe streams.  Blocks neither prefetched nor cached are
+        built and copied here, in one h2d span per batch."""
         devman = self.devman
         self._account_skipping()
         live = self.live_batches
         for i, b in enumerate(live):
             arrs = []
             batch_keys = []
-            for key, build in self._builders(b):
-                if key in prefetched:
-                    prefetched.discard(key)         # pinned at issue
-                    arr = devman.peek(key)
-                    devman.bump(device_prefetch_hits=1)
-                else:
-                    # single-flight: a concurrent query needing the
-                    # same block attaches to one in-flight upload
-                    # instead of issuing its own (shared morsel scans)
-                    arr = devman.get_or_put(key, build, sharding=sh,
-                                            pin=True)
-                pinned.add(key)
-                query_keys.add(key)
-                batch_keys.append(key)
-                arrs.append(arr)
+            upload = span("h2d", self.stats)
+            try:
+                for key, build in self._builders(b):
+                    if key in prefetched:
+                        prefetched.discard(key)         # pinned at issue
+                        arr = devman.peek(key)
+                        devman.bump(device_prefetch_hits=1)
+                    else:
+                        arr = devman.get(key, pin=True)
+                        if arr is None:
+                            # single-flight: a concurrent query needing
+                            # the same block attaches to one in-flight
+                            # upload instead of issuing its own (shared
+                            # morsel scans)
+                            upload.open()
+                            arr = devman.get_or_put(key, build, sharding=sh,
+                                                    pin=True)
+                    pinned.add(key)
+                    query_keys.add(key)
+                    batch_keys.append(key)
+                    arrs.append(arr)
+            finally:
+                upload.close()
             if b in self._gather_sel:
                 # intra-batch savings, counted at consumption: the full
                 # upload would have moved L slots per shard, the gathered
@@ -1073,7 +1128,8 @@ class DistributedScanAgg:
         # concurrent collective dispatch deadlocks the XLA rendezvous (see
         # _DEVICE_DISPATCH_LOCK).  Cross-query sharing still happens — a
         # later query attaches to this one's cached blocks via get_or_put
-        with _DEVICE_DISPATCH_LOCK:
+        with span("device_lock", self.stats) as wait, _DEVICE_DISPATCH_LOCK:
+            self.stats.device_lock_wait_ms += wait.close()
             return self._run_locked(tier, assemble=assemble)
 
     def _run_locked(self, tier: str, assemble=None):  # requires-lock: _DEVICE_DISPATCH_LOCK
@@ -1083,13 +1139,14 @@ class DistributedScanAgg:
         the host as a full (n_groups, K) matrix on that path)."""
         devman = self.devman
         spec = self.spec
+        stats = self.stats
         init_fn, step = _cached_batch_step(spec, self.meta, self.mesh,
-                                           self.batch_rows)
+                                           self.batch_rows, stats=stats)
         step_g = None
         if self.gather is not None:
             _, step_g = _cached_batch_step(spec, self.meta, self.mesh,
                                            self.batch_rows,
-                                           gather=self.gather)
+                                           gather=self.gather, stats=stats)
         axes = _mesh_axes(self.mesh)
         sh = NamedSharding(self.mesh, P(axes if len(axes) > 1 else axes[0]))
         rep_sh = NamedSharding(self.mesh, P())
@@ -1098,28 +1155,33 @@ class DistributedScanAgg:
         pinned: set = set()
         prefetched: set = set()
         try:
-            carry = devman.adopt(carry_key, init_fn(),
-                                 nbytes=self.carry_nbytes, dirty=True)
-            for b, arrs, nxt in self._stream_batches(
-                    sh, query_keys, pinned, prefetched):
-                # the carry is unpinned between batches so a tight budget
-                # may have evicted it (writeback); re-upload before use
-                if carry_key not in devman:
-                    host = devman.take_host(carry_key)
-                    carry = devman.put(carry_key, host, sharding=rep_sh,
-                                       pin=False, dirty=True)
-                devman.pin(carry_key)
-                if nxt is not None:
-                    self._issue_prefetch(nxt, prefetched, query_keys, sh)
-                st = step_g if b in self._gather_sel else step
-                carry = st(carry, *arrs)                # async dispatch
-                devman.unpin(carry_key)
-                devman.adopt(carry_key, carry, nbytes=self.carry_nbytes,
-                             dirty=True)
+            with span("loop", stats):
+                carry = devman.adopt(carry_key, init_fn(),
+                                     nbytes=self.carry_nbytes, dirty=True)
+                for b, arrs, nxt in self._stream_batches(
+                        sh, query_keys, pinned, prefetched):
+                    # the carry is unpinned between batches so a tight
+                    # budget may have evicted it (writeback); re-upload
+                    # before use
+                    if carry_key not in devman:
+                        host = devman.take_host(carry_key)
+                        carry = devman.put(carry_key, host, sharding=rep_sh,
+                                           pin=False, dirty=True)
+                    devman.pin(carry_key)
+                    if nxt is not None:
+                        self._issue_prefetch(nxt, prefetched, query_keys, sh)
+                    st = step_g if b in self._gather_sel else step
+                    with span("step", stats):
+                        carry = st(carry, *arrs)        # async dispatch
+                    devman.unpin(carry_key)
+                    devman.adopt(carry_key, carry, nbytes=self.carry_nbytes,
+                                 dirty=True)
             if assemble is not None:
-                return _assemble_on_device(assemble, self.mesh, carry)
-            out = devman.take_host(carry_key)   # blocks: the final fence
-            return finalize_partials(spec, out)
+                return _assemble_on_device(assemble, self.mesh, stats, carry)
+            with span("fence", stats):
+                out = devman.take_host(carry_key)   # blocks until computed
+            with span("assemble", stats):
+                return finalize_partials(spec, out)
         finally:
             for key in pinned | prefetched:
                 devman.unpin(key)
@@ -1147,15 +1209,17 @@ class DistributedJoinAgg:
     the (card, 1+P) group-build matrix never materialize on host."""
 
     def __init__(self, db, spec: JoinAggSpec, mesh: Mesh,
-                 batch_rows: Optional[int] = None, skip_sets=None):
+                 batch_rows: Optional[int] = None, skip_sets=None,
+                 stats: Optional[ExecStats] = None):
         self.db = db
         self.spec = spec
         self.mesh = mesh
+        self.stats = ExecStats() if stats is None else stats
         skip_sets = skip_sets or {}
         self.pspec = spec.probe_spec()
         self.probe = DistributedScanAgg(
             db, self.pspec, mesh, batch_rows=batch_rows,
-            skip_set=skip_sets.get(spec.probe_table))
+            skip_set=skip_sets.get(spec.probe_table), stats=self.stats)
         self.devman = self.probe.devman
         # build-side streams: bare column streams (no grouping) — the
         # jitted build step applies the build's own conjuncts; a build
@@ -1165,7 +1229,7 @@ class DistributedJoinAgg:
                 db, ScanAggSpec(b.table, [], [], [], [], 1,
                                 list(b.columns)),
                 mesh, batch_rows=batch_rows,
-                skip_set=skip_sets.get(b.table))
+                skip_set=skip_sets.get(b.table), stats=self.stats)
             for b in spec.builds]
         geom = join_agg_geometry(spec, db.catalog, mesh_shards(mesh),
                                  batch_rows)
@@ -1183,7 +1247,8 @@ class DistributedJoinAgg:
         mode = mode or self.choose_mode()
         if mode == "host":
             raise DeviceBudgetError("join does not fit the device tier")
-        with _DEVICE_DISPATCH_LOCK:
+        with span("device_lock", self.stats) as wait, _DEVICE_DISPATCH_LOCK:
+            self.stats.device_lock_wait_ms += wait.close()
             return self._run_locked(assemble)
 
     def _run_locked(self, assemble):  # requires-lock: _DEVICE_DISPATCH_LOCK
@@ -1192,7 +1257,8 @@ class DistributedJoinAgg:
         axes = _mesh_axes(mesh)
         sh = NamedSharding(mesh, P(axes if len(axes) > 1 else axes[0]))
         rep_sh = NamedSharding(mesh, P())
-        dup = _cached_scalar_step("dupmax")
+        stats = self.stats
+        dup = _cached_scalar_step("dupmax", stats)
         query_keys: set = set()
         pinned: set = set()
         prefetched: set = set()
@@ -1207,12 +1273,12 @@ class DistributedJoinAgg:
                                       for ci in child_idx)
                 init_fn, step = _cached_join_build_step(
                     b, stream.meta, mesh, stream.batch_rows,
-                    child_domains)
+                    child_domains, stats=stats)
                 step_g = None
                 if stream.gather is not None:
                     _, step_g = _cached_join_build_step(
                         b, stream.meta, mesh, stream.batch_rows,
-                        child_domains, gather=stream.gather)
+                        child_domains, gather=stream.gather, stats=stats)
                 key = DeviceBlockKeys.carry()
                 btab_keys.append(key)
                 query_keys.add(key)
@@ -1220,52 +1286,61 @@ class DistributedJoinAgg:
                 # build matrices stay pinned for the whole query: later
                 # builds and every probe batch read them (the planner
                 # reserved state_bytes for exactly this residency)
-                btab = devman.adopt(key, init_fn(), nbytes=b.table_bytes,
-                                    dirty=True, pin=True)
-                for bb, arrs, nxt in stream._stream_batches(
-                        sh, query_keys, pinned, prefetched):
-                    if nxt is not None:
-                        stream._issue_prefetch(nxt, prefetched,
-                                               query_keys, sh)
-                    st = step_g if bb in stream._gather_sel else step
-                    btab = st(btab, *children, *arrs)
-                    devman.adopt(key, btab, nbytes=b.table_bytes,
-                                 dirty=True, pin=True)
+                with span("loop", stats):
+                    btab = devman.adopt(key, init_fn(), nbytes=b.table_bytes,
+                                        dirty=True, pin=True)
+                    for bb, arrs, nxt in stream._stream_batches(
+                            sh, query_keys, pinned, prefetched):
+                        if nxt is not None:
+                            stream._issue_prefetch(nxt, prefetched,
+                                                   query_keys, sh)
+                        st = step_g if bb in stream._gather_sel else step
+                        with span("step", stats):
+                            btab = st(btab, *children, *arrs)
+                        devman.adopt(key, btab, nbytes=b.table_bytes,
+                                     dirty=True, pin=True)
                 # runtime uniqueness witness: the single-key gid is only
                 # sound for unique build keys (one code, one group/row)
-                if float(dup(btab)) > 1.0:
+                most = dup(btab)
+                with span("fence", stats):
+                    most = float(most)
+                if most > 1.0:
                     raise _DeviceJoinFallback(
                         f"duplicate join keys in build table {b.table}")
                 btabs.append(btab)
             init_fn, pstep = _cached_join_probe_step(
-                self.spec, self.probe.meta, mesh, self.probe.batch_rows)
+                self.spec, self.probe.meta, mesh, self.probe.batch_rows,
+                stats=stats)
             pstep_g = None
             if self.probe.gather is not None:
                 _, pstep_g = _cached_join_probe_step(
                     self.spec, self.probe.meta, mesh,
-                    self.probe.batch_rows, gather=self.probe.gather)
+                    self.probe.batch_rows, gather=self.probe.gather,
+                    stats=stats)
             edge_btabs = [btabs[bi] for bi, _ in self.spec.probe_edges]
-            carry = devman.adopt(carry_key, init_fn(),
-                                 nbytes=self.probe.carry_nbytes,
-                                 dirty=True)
-            for bb, arrs, nxt in self.probe._stream_batches(
-                    sh, query_keys, pinned, prefetched):
-                if carry_key not in devman:
-                    host = devman.take_host(carry_key)
-                    carry = devman.put(carry_key, host, sharding=rep_sh,
-                                       pin=False, dirty=True)
-                devman.pin(carry_key)
-                if nxt is not None:
-                    self.probe._issue_prefetch(nxt, prefetched,
-                                               query_keys, sh)
-                st = pstep_g if bb in self.probe._gather_sel else pstep
-                carry = st(carry, *edge_btabs, *arrs)
-                devman.unpin(carry_key)
-                devman.adopt(carry_key, carry,
-                             nbytes=self.probe.carry_nbytes, dirty=True)
+            with span("loop", stats):
+                carry = devman.adopt(carry_key, init_fn(),
+                                     nbytes=self.probe.carry_nbytes,
+                                     dirty=True)
+                for bb, arrs, nxt in self.probe._stream_batches(
+                        sh, query_keys, pinned, prefetched):
+                    if carry_key not in devman:
+                        host = devman.take_host(carry_key)
+                        carry = devman.put(carry_key, host, sharding=rep_sh,
+                                           pin=False, dirty=True)
+                    devman.pin(carry_key)
+                    if nxt is not None:
+                        self.probe._issue_prefetch(nxt, prefetched,
+                                                   query_keys, sh)
+                    st = pstep_g if bb in self.probe._gather_sel else pstep
+                    with span("step", stats):
+                        carry = st(carry, *edge_btabs, *arrs)
+                    devman.unpin(carry_key)
+                    devman.adopt(carry_key, carry,
+                                 nbytes=self.probe.carry_nbytes, dirty=True)
             gb = self.spec.group_build
             return _assemble_on_device(
-                assemble, mesh, carry,
+                assemble, mesh, stats, carry,
                 btabs[gb] if gb is not None else None)
         finally:
             for key in pinned | prefetched:
@@ -1324,9 +1399,10 @@ class ParallelExecutor(Executor):
     def execute(self, plan: PlanNode, do_optimize: bool = True):
         from .serving import lower_cached
         mesh = self._default_mesh()
-        phys, rendered, hit = lower_cached(self.db, plan,
-                                           do_optimize=do_optimize,
-                                           distributed=True, mesh=mesh)
+        with span("plan", self.stats):
+            phys, rendered, hit = lower_cached(self.db, plan,
+                                               do_optimize=do_optimize,
+                                               distributed=True, mesh=mesh)
         self.policy = phys.policy
         self.stats.plan_repr = rendered
         self.stats.plan_cache_hit = hit
@@ -1381,10 +1457,13 @@ class ParallelExecutor(Executor):
         spec = phys.scan_agg
         table = self.db.catalog.table(spec.table)
         try:
-            agg = DistributedScanAgg(
-                self.db, spec, self._default_mesh(),
-                batch_rows=getattr(self.db, "device_batch_rows", None),
-                skip_set=phys.core_skip_set())
+            # the batch stream's set-up (batch geometry, zone-map live
+            # batches, gather layout) runs on the host before any step
+            with span("prepare", self.stats):
+                agg = DistributedScanAgg(
+                    self.db, spec, self._default_mesh(),
+                    batch_rows=getattr(self.db, "device_batch_rows", None),
+                    skip_set=phys.core_skip_set(), stats=self.stats)
         except Exception as e:
             return self._fall_back(e)
         tier = "resident" if phys.agg_tier == TIER_DEVICE_RESIDENT \
@@ -1406,11 +1485,12 @@ class ParallelExecutor(Executor):
         if agg.delta_rows:
             # merge-on-read visibility: the scan consumed a delta tail
             agg.devman.bump(delta_rows=agg.delta_rows)
-        if assemble is not None:
-            gids, vals, _pay = out
-            result = self._assemble(spec, vals, table, gids=gids)
-        else:
-            result = self._assemble(spec, out, table)
+        with span("assemble", self.stats):
+            if assemble is not None:
+                gids, vals, _pay = out
+                result = self._assemble(spec, vals, table, gids=gids)
+            else:
+                result = self._assemble(spec, out, table)
         # close the device-counter window BEFORE the suffix runs (its host
         # program threads the same delta fields through run_program)
         end = stats_base(dm, fields)
@@ -1432,10 +1512,13 @@ class ParallelExecutor(Executor):
         jspec = phys.join_agg
         tables = [jspec.probe_table] + [b.table for b in jspec.builds]
         try:
-            agg = DistributedJoinAgg(
-                self.db, jspec, self._default_mesh(),
-                batch_rows=getattr(self.db, "device_batch_rows", None),
-                skip_sets={t: phys.skip_set_for_table(t) for t in tables})
+            with span("prepare", self.stats):
+                agg = DistributedJoinAgg(
+                    self.db, jspec, self._default_mesh(),
+                    batch_rows=getattr(self.db, "device_batch_rows", None),
+                    skip_sets={t: phys.skip_set_for_table(t)
+                               for t in tables},
+                    stats=self.stats)
         except Exception as e:
             return self._fall_back(e)
         mode = phys.join_mode or "streamed"
@@ -1462,7 +1545,8 @@ class ParallelExecutor(Executor):
             return self._fall_back(e)
         if agg.delta_rows:
             agg.devman.bump(delta_rows=agg.delta_rows)
-        result = self._assemble_join(jspec, gids, vals, pay)
+        with span("assemble", self.stats):
+            result = self._assemble_join(jspec, gids, vals, pay)
         end = stats_base(dm, fields)
         if phys.suffix_plan is not None and not device_sorted:
             try:
@@ -1536,12 +1620,13 @@ class ParallelExecutor(Executor):
         host program against a one-table catalog holding the (tiny) core
         result.  Stats and policy are shared, so suffix sorts/limits that
         spill are counted against this query."""
-        sdb = _SuffixDatabase(table, self.bufman)
-        sub = Executor(sdb)
-        sub.stats = self.stats
-        sub.policy = self.policy
-        prog = compile_plan(suffix_plan, sdb.catalog)
-        return sub.run_program(prog)
+        with span("suffix", self.stats):
+            sdb = _SuffixDatabase(table, self.bufman)
+            sub = Executor(sdb)
+            sub.stats = self.stats
+            sub.policy = self.policy
+            prog = compile_plan(suffix_plan, sdb.catalog)
+            return sub.run_program(prog)
 
     def _assemble(self, spec: ScanAggSpec, out: np.ndarray, table,
                   gids: Optional[np.ndarray] = None):

@@ -209,15 +209,18 @@ class Query:
 
     ``db.scan("lineitem").filter(...).group_by(...).agg(...)`` etc.  Executed
     via ``.execute()`` (returns a result Table) through the session's
-    executor with optimization enabled.
+    executor with optimization enabled.  ``spans`` (an ``ExecStats``)
+    holds span totals recorded while the query was made (the SQL parse);
+    the first execution takes them over.
     """
 
-    def __init__(self, plan: PlanNode, database):
+    def __init__(self, plan: PlanNode, database, spans=None):
         self.plan = plan
         self.database = database
+        self.spans = spans
 
     def _wrap(self, plan) -> "Query":
-        return Query(plan, self.database)
+        return Query(plan, self.database, self.spans)
 
     def filter(self, predicate: Expr) -> "Query":
         return self._wrap(FilterNode(self.plan, predicate))
@@ -278,7 +281,7 @@ class Query:
         return plan_repr(plan)
 
     def execute(self, **kw):
-        return self.database.execute_plan(self.plan, **kw)
+        return self.database.execute_plan(self.plan, spans=self.spans, **kw)
 
     def to_dict(self, **kw):
         return self.execute(**kw).to_pydict()

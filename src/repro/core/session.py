@@ -24,11 +24,12 @@ from typing import Optional
 
 import numpy as np
 
-from .executor import Executor
+from .executor import ExecStats, Executor
 from .indexes import IndexManager
 from .relalg import PlanNode, Query, ScanNode
 from .storage import Storage
 from .table import Table
+from .tracing import span, take
 from .transactions import Transaction, TransactionManager
 from .types import DBType
 
@@ -38,6 +39,9 @@ _open_lock = threading.Lock()
 # device-cache key namespaces for transaction snapshots (0 = committed
 # catalog; see Connection.query)
 _snapshot_ns = itertools.count(1)
+
+# the ``query`` metadata of each query's mdb.query span (ExecStats.query_id)
+_query_ids = itertools.count(1)
 
 
 class DatabaseError(RuntimeError):
@@ -311,7 +315,10 @@ class Database:
     def sql(self, text: str) -> Query:
         from .sqlparser import parse_sql
         self._check_alive()
-        return Query(parse_sql(text, self.catalog), self)
+        parsed = ExecStats()        # the parse's span, until a query runs
+        with span("parse", parsed):
+            plan = parse_sql(text, self.catalog)
+        return Query(plan, self, spans=parsed)
 
     def delete(self, name: str, predicate) -> int:
         """DELETE FROM name WHERE predicate.  Tables are immutable values,
@@ -375,16 +382,23 @@ class Database:
         self._stats_local.stats = value
 
     def execute_plan(self, plan: PlanNode, do_optimize: bool = True,
-                     distributed: bool = False, mesh=None) -> Table:
+                     distributed: bool = False, mesh=None,
+                     spans: Optional[ExecStats] = None) -> Table:
+        """Run ``plan``; ``spans`` holds span totals recorded for it
+        before it ran (the SQL parse), moved into this query's stats."""
         self._check_alive()
         if distributed:
             from .parallel import ParallelExecutor
             ex = ParallelExecutor(self, mesh=mesh)
         else:
             ex = Executor(self)
-        self.last_stats = ex.stats
+        self.last_stats = st = ex.stats
+        if spans is not None:
+            take(st, spans)
+        st.query_id = next(_query_ids)
         # query scope: cleanup() defers spill-file deletion while we run
-        with self.buffer_manager.query_scope():
+        with self.buffer_manager.query_scope(), \
+                span("query", st, query=st.query_id):
             return ex.execute(plan, do_optimize=do_optimize)
 
     # ---- hooks (storage + indexes) -------------------------------------------

@@ -6,7 +6,7 @@ performance to (a) row-wise storage forcing whole-table scans and (b)
 per-tuple interpretation overhead.  Per the "implement the baseline too"
 rule, this module is that comparator: the same logical plans interpreted
 through Python-level row iterators with per-row expression evaluation.
-Benchmarks run identical queries through both engines (bench_tpch.py).
+Tests run identical queries through both engines and compare the answers.
 
 It materializes rows as dicts — intentionally; the point of the baseline is
 the processing *model*, not an optimized row engine.

@@ -3,7 +3,7 @@
 The American Community Survey benchmark uses a 274-column mixed-type table
 (~millions of census rows).  We synthesize the same shape: person records
 with replicate weights, demographic categoricals, and numeric amounts, so
-bench_acs.py can run the paper's load + statistics pipeline."""
+examples/acs_survey.py can run the paper's load + statistics pipeline."""
 
 from __future__ import annotations
 

@@ -3,8 +3,7 @@
 Generates all 8 TPC-H tables at a given scale factor with dbgen-like
 cardinalities and value domains (uniform approximations of dbgen's
 distributions — the benchmark exercises the same operator mix).  Used by
-benchmarks/bench_tpch.py (paper Table 1), bench_ingest (Fig. 5) and
-bench_export (Fig. 6).
+the tests, ``chip_smoke.py`` and the examples.
 """
 
 from __future__ import annotations
