@@ -513,6 +513,8 @@ class ExecStats:
     device_lock_wait_ms: float = 0.0    # wait for the device dispatch lock
     programs_built: int = 0             # device programs this query built
                                         # (each compiles at its first call)
+    dense_reduce_steps: int = 0         # batch steps whose group merge
+                                        # ran as the dense masked reduction
     query_id: int = 0                   # the ``query`` of its mdb.query span
     reserved_bytes: int = 0             # host reservation the gate granted
     reserved_device_bytes: int = 0      # device reservation granted

@@ -113,13 +113,55 @@ def _fragment_mask_gid(spec: ScanAggSpec, meta: dict, valid, arrays):
     return mask, gid
 
 
+# A segment reduction into at most this many groups runs densely (see
+# ``_segment_reduce``); above it, as XLA's scatter.  On one TPU v5e, per
+# 65,536-row batch, the dense form takes about 0.38 us per group and lane
+# and the scatter 4 to 7 ms whatever the groups: 15 lanes (Q1's) cross
+# over near 880 groups (scripts/segment_reduce_crossover.py, PERF.md §6).
+DENSE_REDUCE_MAX_GROUPS = 512
+
+# op -> (dense reduction over rows, scatter fallback, empty-group identity)
+_SEGMENT_OPS = {
+    "sum": (jnp.sum, jax.ops.segment_sum, 0.0),
+    "min": (jnp.min, jax.ops.segment_min, np.inf),
+    "max": (jnp.max, jax.ops.segment_max, -np.inf),
+}
+
+
+def _dense_reduce(n_groups: int) -> bool:
+    """Whether a segment reduction into ``n_groups`` groups runs densely."""
+    return n_groups <= DENSE_REDUCE_MAX_GROUPS
+
+
+def _segment_reduce(op: str, values, gid, n_groups: int):
+    """Per-group ``op`` ("sum", "min" or "max") of ``values`` (rows,) or
+    (rows, K) by ``gid`` in [0, n_groups): (n_groups,) or (n_groups, K),
+    an empty group holding the identity (0, +inf, -inf).
+
+    XLA runs a scatter whose indices collide as a serial loop over the
+    rows.  For a small group domain the dense form is one fused reduce:
+    every row is compared with every group id, the values masked with the
+    identity where they differ, and the result reduced over the rows."""
+    reduce, scatter, identity = _SEGMENT_OPS[op]
+    if not _dense_reduce(n_groups):
+        return scatter(values, gid, num_segments=n_groups)
+    if n_groups == 1:
+        return reduce(values, axis=0, keepdims=True)
+    hit = gid[:, None] == jnp.arange(n_groups, dtype=gid.dtype)
+    if values.ndim == 2:
+        hit, values = hit[:, :, None], values[:, None, :]
+    else:
+        values = values[:, None]
+    return reduce(jnp.where(hit, values, identity), axis=0)
+
+
 def _fragment_partials(spec: ScanAggSpec, meta: dict, mask, gid, arrays,
                        data_axis):
     """Shared SPMD core: evaluate every aggregate expression once, stack
-    the sum-like columns in ``partial_layout`` order into ONE segment_sum
+    the sum-like columns in ``partial_layout`` order into ONE segment sum
     + ONE psum (paper Fig. 2 per-chunk work, MAL-fused), and merge each
-    min/max via its own segment+collective.  Returns (seg, extras) —
-    mergeable raw partials, not yet finalized."""
+    min/max via its own segment reduction + collective.  Returns (seg,
+    extras) — mergeable raw partials, not yet finalized."""
     layout = partial_layout(spec)
     sum_cols = [mask.astype(jnp.float64)]            # cnt_star
     evals = {}
@@ -134,7 +176,7 @@ def _fragment_partials(spec: ScanAggSpec, meta: dict, mask, gid, arrays,
         if a.fn in ("sum", "avg"):
             sum_cols.append(jnp.where(ok, f, 0.0))
     stacked = jnp.stack(sum_cols, axis=1)            # (rows, n_sum)
-    seg = jax.ops.segment_sum(stacked, gid, num_segments=spec.n_groups)
+    seg = _segment_reduce("sum", stacked, gid, spec.n_groups)
     seg = jax.lax.psum(seg, data_axis)               # one collective
     big = jnp.float64(np.inf)
     extras = {}
@@ -142,12 +184,12 @@ def _fragment_partials(spec: ScanAggSpec, meta: dict, mask, gid, arrays,
         ok, f = evals[i]
         if fn == "min":
             v = jnp.where(ok, f, big)
-            s = jax.lax.pmin(jax.ops.segment_min(
-                v, gid, num_segments=spec.n_groups), data_axis)
+            s = jax.lax.pmin(_segment_reduce(
+                "min", v, gid, spec.n_groups), data_axis)
         else:
             v = jnp.where(ok, f, -big)
-            s = jax.lax.pmax(jax.ops.segment_max(
-                v, gid, num_segments=spec.n_groups), data_axis)
+            s = jax.lax.pmax(_segment_reduce(
+                "max", v, gid, spec.n_groups), data_axis)
         extras[out_col] = s
     return seg, extras
 
@@ -1142,6 +1184,7 @@ class DistributedScanAgg:
         stats = self.stats
         init_fn, step = _cached_batch_step(spec, self.meta, self.mesh,
                                            self.batch_rows, stats=stats)
+        dense = _dense_reduce(spec.n_groups)
         step_g = None
         if self.gather is not None:
             _, step_g = _cached_batch_step(spec, self.meta, self.mesh,
@@ -1173,6 +1216,7 @@ class DistributedScanAgg:
                     st = step_g if b in self._gather_sel else step
                     with span("step", stats):
                         carry = st(carry, *arrs)        # async dispatch
+                    stats.dense_reduce_steps += dense
                     devman.unpin(carry_key)
                     devman.adopt(carry_key, carry, nbytes=self.carry_nbytes,
                                  dirty=True)
@@ -1318,6 +1362,7 @@ class DistributedJoinAgg:
                     self.probe.batch_rows, gather=self.probe.gather,
                     stats=stats)
             edge_btabs = [btabs[bi] for bi, _ in self.spec.probe_edges]
+            dense = _dense_reduce(self.pspec.n_groups)
             with span("loop", stats):
                 carry = devman.adopt(carry_key, init_fn(),
                                      nbytes=self.probe.carry_nbytes,
@@ -1335,6 +1380,7 @@ class DistributedJoinAgg:
                     st = pstep_g if bb in self.probe._gather_sel else pstep
                     with span("step", stats):
                         carry = st(carry, *edge_btabs, *arrs)
+                    stats.dense_reduce_steps += dense
                     devman.unpin(carry_key)
                     devman.adopt(carry_key, carry,
                                  nbytes=self.probe.carry_nbytes, dirty=True)
