@@ -23,7 +23,10 @@ The last line of standard output is one JSON object (``correct``,
 ``breakdown``, and ``checks``: each number compared with its limit).  The
 numbers compared are also the last lines of standard error.  Without an
 accelerator, or with fewer chips than the cell asks for, the run exits 2
-and prints no result.
+and prints no result.  A run that cannot measure the device exits 3 and
+prints no result: when a warm-up query fails as a window query would
+(it raised, ran on the host, or fell back to it), or when a traced run
+on the chip finds no device operation inside its window.
 
 Everything that belongs to one configuration, traffic mix, query template
 or per-layer metric is found by name: ``bench/configs/<config>.json``
@@ -58,6 +61,11 @@ CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
 
 class NoChip(RuntimeError):
     """JAX finds no accelerator, or fewer chips than the cell asks for."""
+
+
+class OffDevice(RuntimeError):
+    """The run cannot measure the device: a warm-up query left the device
+    tier, or a traced chip run saw no device operation in its window."""
 
 
 @dataclass
@@ -281,8 +289,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     phases["load_s"] = time.perf_counter() - t0
     for t in dict.fromkeys(traffic["mix"]):
         r = run_query(jax, db, t, template(t).SQL, mesh)
-        if r.error:
-            raise RuntimeError(f"warm-up {t}: {r.error}")
+        if failed(r):           # the window's rule, before the window
+            db.shutdown()
+            raise OffDevice(
+                f"warm-up {t} left the device tier: tier "
+                f"{r.stats.get('device_tier', '')!r}, fallback "
+                f"{r.stats.get('device_fallback', '')!r}, error {r.error!r}")
         phases[f"warmup_{t}_s"] = r.end - r.start
     setup_s = time.perf_counter() - t_start
     phases.update(counts)
@@ -315,10 +327,21 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
     db.shutdown()
     del db
+    if trace:
+        require_device_ops(summary, require_tpu)
 
     checks = check_answers(win.records, traffic["mix"], data)
     return result(cell, win, summary, checks, setup_s, data, devices, peak,
                   trace)
+
+
+def require_device_ops(summary, require_tpu: bool) -> None:
+    """A traced run on the chip whose trace has no window span, or no
+    device operation inside it (``summary`` None), measured nothing of the
+    device.  The CPU's trace has no device plane, so the checks' runs
+    (``require_tpu`` false) pass."""
+    if require_tpu and summary is None:
+        raise OffDevice("the traced window holds no device operation")
 
 
 def check_answers(records: list, mix: list, data: dict) -> dict:
@@ -435,6 +458,9 @@ def main(argv=None) -> int:
     except NoChip as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
+    except OffDevice as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 3
     print(json.dumps(out.pop("diagnostics")), file=sys.stderr)
     for k, c in out["checks"].items():
         print(f"{k} {c['value']!r} limit {c['limit']!r}", file=sys.stderr)
