@@ -111,8 +111,8 @@ def streamed_budget(db) -> int:
 
 def want_tier(cell: str, name: str, sf: float) -> str:
     """The tier each query must report.  Q3 joins on o_orderkey, whose
-    dense domain (1.5M keys per unit of scale) fits the device join only up
-    to MAX_DEVICE_JOIN_DOMAIN; above that the planner keeps it on the host
+    distinct keys (1.5M per unit of scale) fit the device join only up to
+    MAX_DEVICE_JOIN_DOMAIN; above that the planner keeps it on the host
     ("")."""
     from repro.core.physplan import MAX_DEVICE_JOIN_DOMAIN
     from repro.data.tpch import SF_ROWS

@@ -506,7 +506,7 @@ class ExecStats:
     plan_cache_hit: bool = False        # lowering skipped via the plan cache
     admission_wait_ms: float = 0.0      # time queued at the admission gate
     # spans (tracing.py): this query's totals per span name — milliseconds
-    # and times closed — of parse, plan, admit, prepare, device_lock,
+    # and times closed — of parse, plan, admit, prepare, rank, device_lock,
     # loop, step, h2d, fence, assemble, suffix, compile and the query
     span_ms: dict = field(default_factory=dict)
     span_n: dict = field(default_factory=dict)
@@ -515,6 +515,8 @@ class ExecStats:
                                         # (each compiles at its first call)
     dense_reduce_steps: int = 0         # batch steps whose group merge
                                         # ran as the dense masked reduction
+    join_ranked_builds: int = 0         # device join builds coded by rank
+    join_rank_keys: int = 0             # distinct keys across those builds
     query_id: int = 0                   # the ``query`` of its mdb.query span
     reserved_bytes: int = 0             # host reservation the gate granted
     reserved_device_bytes: int = 0      # device reservation granted

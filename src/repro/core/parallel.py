@@ -62,7 +62,8 @@ from .executor import (ExecStats, Executor, _res_nulls,  # noqa: E402
 from .expression import EvalContext, Expr, ExprResult  # noqa: E402
 from .physplan import (AGG_RESULT_NAME, DeviceBuild,  # noqa: E402
                        JoinAggSpec, PhysicalPlan, ScanAggSpec,
-                       TIER_DEVICE_RESIDENT, choose_device_join_tier,
+                       TIER_DEVICE_RESIDENT, build_keys,
+                       choose_device_join_tier,
                        choose_device_tier, default_mesh, join_agg_geometry,
                        match_scan_agg,  # noqa: F401  (re-exported for tests)
                        mesh_shards, partial_layout, scan_agg_geometry)
@@ -87,11 +88,9 @@ def _eval_jnp(expr: Expr, arrays: dict, meta: dict) -> ExprResult:
     return expr.eval(ctx)
 
 
-def _fragment_mask_gid(spec: ScanAggSpec, meta: dict, valid, arrays):
-    """Shared SPMD prologue: the filter mask and the dense mixed-radix gid.
-    One definition serves both the single-shot fragment and the batched
-    raw-partial fragment — any fix to NULL masking or domain decoding
-    lands in both, preserving their bit-identity."""
+def _fragment_mask(spec, meta: dict, valid, arrays):
+    """The filter mask: valid rows that pass every conjunct of ``spec`` (a
+    ``ScanAggSpec`` or a ``DeviceBuild``)."""
     mask = valid
     for conj in spec.conjuncts:
         r = _eval_jnp(conj, arrays, meta)
@@ -99,6 +98,15 @@ def _fragment_mask_gid(spec: ScanAggSpec, meta: dict, valid, arrays):
         if r.null is not None:
             m = m & ~r.null
         mask = mask & m
+    return mask
+
+
+def _fragment_mask_gid(spec: ScanAggSpec, meta: dict, valid, arrays):
+    """Shared SPMD prologue: the filter mask and the dense mixed-radix gid.
+    One definition serves both the single-shot fragment and the batched
+    raw-partial fragment — any fix to NULL masking or domain decoding
+    lands in both, preserving their bit-identity."""
+    mask = _fragment_mask(spec, meta, valid, arrays)
     if spec.group_keys:
         gid = jnp.zeros(valid.shape, dtype=jnp.int32)
         for k, (off, card) in zip(spec.group_keys, spec.key_domains):
@@ -194,20 +202,48 @@ def _fragment_partials(spec: ScanAggSpec, meta: dict, mask, gid, arrays,
     return seg, extras
 
 
-def _join_edge_mask(arrays, meta: dict, mask, edge_cols, domains, btabs):
-    """Shared probe-side join gating: for each equi-join edge, exclude rows
-    whose local key is NULL, outside the build's dense domain, or absent
-    from the build table (presence lane 0 == 0).  The domain comparison
-    runs in float64 *before* the int32 narrowing — an out-of-domain key
-    must never alias a clipped in-domain code."""
-    for cname, (off, card), btab in zip(edge_cols, domains, btabs):
-        kv = arrays[cname]
-        sent = NULL_SENTINEL[meta[cname][0]]
+def _key_code(kv, sent, domain, keys=None):
+    """``(ok, code)``: whether each join-key value has a row of the build
+    matrix, and which.  A dense domain ``(offset, card)`` codes a key as
+    its distance from the offset; the comparison runs in float64 *before*
+    the int32 narrowing, so an out-of-domain key never aliases a clipped
+    in-domain code.  A ranked domain (``keys``, the build's sorted
+    distinct keys) codes a key as its position there, and only a key equal
+    to the one at that position matches: a key between two build keys,
+    below the smallest or above the largest never takes a neighbour's
+    rank.  NULL (``sent``) never matches."""
+    if keys is None:
+        off, card = domain
         codef = kv.astype(jnp.float64) - off
         ok = (kv != sent) & (codef >= 0) & (codef < card)
-        code = jnp.clip(codef, 0, card - 1).astype(jnp.int32)
+        return ok, jnp.clip(codef, 0, card - 1).astype(jnp.int32)
+    pos = jnp.clip(jnp.searchsorted(keys, kv), 0, keys.shape[0] - 1) \
+        .astype(jnp.int32)
+    return (kv != sent) & (keys[pos] == kv), pos
+
+
+def _join_edge_mask(arrays, meta: dict, mask, edge_cols, domains, btabs,
+                    keys):
+    """Shared probe-side join gating: for each equi-join edge, exclude rows
+    whose local key is NULL, has no row in the build's domain, or is absent
+    from the build table (presence lane 0 == 0).  ``keys`` holds, per edge,
+    the ranked build's sorted keys, or None for a dense domain.  Returns
+    the mask and each edge's codes."""
+    codes = []
+    for cname, dom, btab, k in zip(edge_cols, domains, btabs, keys):
+        ok, code = _key_code(arrays[cname], NULL_SENTINEL[meta[cname][0]],
+                             dom, k)
         mask = mask & ok & (btab[code, 0] > 0)
-    return mask
+        codes.append(code)
+    return mask, codes
+
+
+def _take_keys(builds, args) -> list:
+    """Per build, its sorted keys from ``args`` (the replicated key
+    arguments of a join step, one per ranked build in order), or None for
+    a dense domain."""
+    args = iter(args)
+    return [None if b.ranked is None else next(args) for b in builds]
 
 
 def make_fragment(spec: ScanAggSpec, meta: dict, data_axis: str = "data"):
@@ -485,9 +521,12 @@ def _cached_batch_step(spec: ScanAggSpec, meta: dict, mesh: Mesh,
 
 
 def build_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
-                          child_domains, gather=None):
+                          children, gather=None):
     """(init_fn, step_fn) for one join build table:
-    ``step(btab, *child_btabs, valid, *cols) -> btab'``.
+    ``step(btab, *child_btabs, *keys, valid, *cols) -> btab'``, where
+    ``children`` are the builds of ``build.probe_edges`` and ``keys`` the
+    sorted keys of each ranked one of ``build`` and its children, in that
+    order.
 
     One batch of the build table's stream is filtered (its own conjuncts +
     NULL/domain/presence gating against already-built child tables) and
@@ -500,27 +539,23 @@ def build_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
     so dirty-writeback/eviction compose unchanged."""
     axes = _mesh_axes(mesh)
     rowspec = P(axes if len(axes) > 1 else axes[0])
-    off, card = build.domain
+    card = build.domain[1]
     width = 1 + len(build.payload)
-    n_children = len(build.probe_edges)
+    own = [build, *children]
+    # replicated arguments: the child matrices, then the ranked keys
+    n_rep = len(children) + sum(b.ranked is not None for b in own)
     edge_cols = [c for _, c in build.probe_edges]
 
-    def fragment(child_btabs, valid, *cols):
+    def fragment(rep, valid, *cols):
         arrays = dict(zip(build.columns, cols))
-        mask = valid
-        for conj in build.conjuncts:
-            r = _eval_jnp(conj, arrays, meta)
-            m = r.values != 0
-            if r.null is not None:
-                m = m & ~r.null
-            mask = mask & m
-        kv = arrays[build.key]
-        sent = NULL_SENTINEL[meta[build.key][0]]
-        codef = kv.astype(jnp.float64) - off
-        mask = mask & (kv != sent) & (codef >= 0) & (codef < card)
-        code = jnp.clip(codef, 0, card - 1).astype(jnp.int32)
-        mask = _join_edge_mask(arrays, meta, mask, edge_cols,
-                               child_domains, child_btabs)
+        keys = _take_keys(own, rep[len(children):])
+        mask = _fragment_mask(build, meta, valid, arrays)
+        ok, code = _key_code(arrays[build.key],
+                             NULL_SENTINEL[meta[build.key][0]],
+                             build.domain, keys[0])
+        mask, _ = _join_edge_mask(arrays, meta, mask & ok, edge_cols,
+                                  [c.domain for c in children],
+                                  rep[:len(children)], keys[1:])
         lanes = [mask.astype(jnp.float64)]
         for p in build.payload:
             lanes.append(jnp.where(mask, arrays[p].astype(jnp.float64),
@@ -531,19 +566,18 @@ def build_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
 
     if gather is None:
         def shard_fn(*args):
-            return fragment(args[:n_children], args[n_children],
-                            *args[n_children + 1:])
+            return fragment(args[:n_rep], args[n_rep], *args[n_rep + 1:])
         n_rows_in = 1 + len(build.columns)
     else:
         def shard_fn(*args):
-            inv = args[n_children]
-            v, full = _gather_expand(gather, inv, args[n_children + 1],
-                                     args[n_children + 2:])
-            return fragment(args[:n_children], v, *full)
+            inv = args[n_rep]
+            v, full = _gather_expand(gather, inv, args[n_rep + 1],
+                                     args[n_rep + 2:])
+            return fragment(args[:n_rep], v, *full)
         n_rows_in = 2 + len(build.columns)
     sm = jax.shard_map(
         shard_fn, mesh=mesh,
-        in_specs=(P(),) * n_children + (rowspec,) * n_rows_in,
+        in_specs=(P(),) * n_rep + (rowspec,) * n_rows_in,
         out_specs=P(), check_vma=False)
 
     def join_build_step(btab, *args):
@@ -558,15 +592,15 @@ def build_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
 
 
 def _cached_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
-                            batch_rows: int, child_domains, gather=None,
+                            batch_rows: int, children, gather=None,
                             stats=None):
     key = ("jbuild", build.table, repr(build.conjuncts), build.key,
-           build.domain, tuple(build.payload), tuple(build.probe_edges),
-           tuple(child_domains), _meta_key(build.columns, meta),
-           batch_rows, gather, id(mesh.devices.flat[0]),
-           tuple(mesh.shape.items()))
+           build.domain_key, tuple(build.payload), tuple(build.probe_edges),
+           tuple(c.domain_key for c in children),
+           _meta_key(build.columns, meta), batch_rows, gather,
+           id(mesh.devices.flat[0]), tuple(mesh.shape.items()))
     return _cached_program(
-        key, lambda: build_join_build_step(build, meta, mesh, child_domains,
+        key, lambda: build_join_build_step(build, meta, mesh, children,
                                            gather=gather),
         stats)
 
@@ -574,26 +608,37 @@ def _cached_join_build_step(build: DeviceBuild, meta: dict, mesh: Mesh,
 def build_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
                           gather=None):
     """(init_fn, step_fn) for the probe (fact) side of a device join:
-    ``step(carry, *edge_btabs, valid, *cols) -> carry'``.
+    ``step(carry, *edge_btabs, *keys, valid, *cols) -> carry'``, ``keys``
+    the sorted keys of each ranked probe-adjacent build.
 
     The probe phase IS the scan-agg batch step over the probe table —
     identical prologue, partials and carry combine — plus presence gating
     through every probe-adjacent build matrix.  The gid is the group
-    build's key code; rows with NULL / out-of-domain / unmatched keys are
-    masked and contribute the combine identity."""
+    build's key code (for a ranked group build, the code its probe edge
+    gives); rows with NULL / out-of-domain / unmatched keys are masked and
+    contribute the combine identity."""
     pspec = spec.probe_spec()
     axes = _mesh_axes(mesh)
     rowspec = P(axes if len(axes) > 1 else axes[0])
     layout = partial_layout(pspec)
-    domains = [spec.builds[bi].domain for bi, _ in spec.probe_edges]
+    edges = [spec.builds[bi] for bi, _ in spec.probe_edges]
+    domains = [b.domain for b in edges]
     edge_cols = [c for _, c in spec.probe_edges]
-    n_children = len(spec.probe_edges)
+    n_edges = len(edges)
+    n_rep = n_edges + sum(b.ranked is not None for b in edges)
+    gb = spec.group_build
+    ranked_gid = None
+    if gb is not None and spec.builds[gb].ranked is not None:
+        ranked_gid = [bi for bi, _ in spec.probe_edges].index(gb)
 
-    def fragment(edge_btabs, valid, *cols):
+    def fragment(rep, valid, *cols):
         arrays = dict(zip(pspec.columns, cols))
+        keys = _take_keys(edges, rep[n_edges:])
         mask, gid = _fragment_mask_gid(pspec, meta, valid, arrays)
-        mask = _join_edge_mask(arrays, meta, mask, edge_cols, domains,
-                               edge_btabs)
+        mask, codes = _join_edge_mask(arrays, meta, mask, edge_cols,
+                                      domains, rep[:n_edges], keys)
+        if ranked_gid is not None:      # the dense gid above goes unused
+            gid = codes[ranked_gid]
         seg, extras = _fragment_partials(pspec, meta, mask, gid, arrays,
                                          axes)
         if not extras:
@@ -603,19 +648,18 @@ def build_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
 
     if gather is None:
         def shard_fn(*args):
-            return fragment(args[:n_children], args[n_children],
-                            *args[n_children + 1:])
+            return fragment(args[:n_rep], args[n_rep], *args[n_rep + 1:])
         n_rows_in = 1 + len(pspec.columns)
     else:
         def shard_fn(*args):
-            inv = args[n_children]
-            v, full = _gather_expand(gather, inv, args[n_children + 1],
-                                     args[n_children + 2:])
-            return fragment(args[:n_children], v, *full)
+            inv = args[n_rep]
+            v, full = _gather_expand(gather, inv, args[n_rep + 1],
+                                     args[n_rep + 2:])
+            return fragment(args[:n_rep], v, *full)
         n_rows_in = 2 + len(pspec.columns)
     sm = jax.shard_map(
         shard_fn, mesh=mesh,
-        in_specs=(P(),) * n_children + (rowspec,) * n_rows_in,
+        in_specs=(P(),) * n_rep + (rowspec,) * n_rows_in,
         out_specs=P(), check_vma=False)
     kinds = layout.kinds
 
@@ -643,7 +687,7 @@ def _cached_join_probe_step(spec: JoinAggSpec, meta: dict, mesh: Mesh,
            tuple(pspec.group_keys), tuple(pspec.key_domains),
            tuple((a.fn, repr(a.expr)) for a in pspec.aggs),
            tuple(spec.probe_edges),
-           tuple(b.domain for b in spec.builds),
+           tuple(b.domain_key for b in spec.builds),
            _meta_key(pspec.columns, meta), pspec.n_groups,
            batch_rows, gather, id(mesh.devices.flat[0]),
            tuple(mesh.shape.items()))
@@ -1281,6 +1325,31 @@ class DistributedJoinAgg:
         self.working_bytes = geom.working_bytes
         self.delta_rows = self.probe.delta_rows \
             + sum(s.delta_rows for s in self.builds)
+        self.rank_blocks = [None if b.ranked is None
+                            else self._upload_ranks(b, stream)
+                            for b, stream in zip(spec.builds, self.builds)]
+
+    def _upload_ranks(self, build: DeviceBuild, stream) -> tuple:
+        """A ranked build's sorted keys in HBM, as a block of the device
+        cache: the cached keys of the table's version (or their
+        distinct-and-sort) and, on a miss, the upload.  The block depends
+        on every row, so it is keyed like a delta-tail block, by the base
+        version and the delta epoch: an append retires it
+        (``invalidate_delta``), as any other write does.  Returns the
+        block's ``(key, host keys)``."""
+        with span("rank", self.stats):
+            keys = build_keys(stream.table, build.key)
+            if keys is not build.ranked.keys \
+                    and not np.array_equal(keys, build.ranked.keys):
+                raise _DeviceJoinFallback(
+                    f"join keys of build table {build.table} changed "
+                    "since planning")
+            key = DeviceBlockKeys.column(
+                build.table, "#ranks:" + build.key, stream.delta_version_key,
+                (stream.mesh_key, "replicated"))
+            self.devman.get_or_put(key, lambda: keys,
+                                   sharding=NamedSharding(self.mesh, P()))
+        return key, keys
 
     def choose_mode(self) -> str:
         return choose_device_join_tier(
@@ -1311,22 +1380,34 @@ class DistributedJoinAgg:
         carry_key = DeviceBlockKeys.carry()
         query_keys.add(carry_key)
         try:
-            for b, stream in zip(self.spec.builds, self.builds):
+            # each ranked build's keys, pinned for the query (None: dense)
+            keys = []
+            for blk in self.rank_blocks:
+                if blk is not None:
+                    key, host = blk
+                    blk = devman.get_or_put(key, lambda host=host: host,
+                                            sharding=rep_sh, pin=True)
+                    pinned.add(key)
+                    query_keys.add(key)
+                keys.append(blk)
+            for bi, (b, stream) in enumerate(zip(self.spec.builds,
+                                                 self.builds)):
                 child_idx = [ci for ci, _ in b.probe_edges]
-                child_domains = tuple(self.spec.builds[ci].domain
-                                      for ci in child_idx)
+                kids = [self.spec.builds[ci] for ci in child_idx]
                 init_fn, step = _cached_join_build_step(
-                    b, stream.meta, mesh, stream.batch_rows,
-                    child_domains, stats=stats)
+                    b, stream.meta, mesh, stream.batch_rows, kids,
+                    stats=stats)
                 step_g = None
                 if stream.gather is not None:
                     _, step_g = _cached_join_build_step(
-                        b, stream.meta, mesh, stream.batch_rows,
-                        child_domains, gather=stream.gather, stats=stats)
+                        b, stream.meta, mesh, stream.batch_rows, kids,
+                        gather=stream.gather, stats=stats)
                 key = DeviceBlockKeys.carry()
                 btab_keys.append(key)
                 query_keys.add(key)
-                children = [btabs[ci] for ci in child_idx]
+                children = [btabs[ci] for ci in child_idx] + [
+                    keys[i] for i in [bi, *child_idx]
+                    if keys[i] is not None]
                 # build matrices stay pinned for the whole query: later
                 # builds and every probe batch read them (the planner
                 # reserved state_bytes for exactly this residency)
@@ -1351,6 +1432,9 @@ class DistributedJoinAgg:
                 if most > 1.0:
                     raise _DeviceJoinFallback(
                         f"duplicate join keys in build table {b.table}")
+                if b.ranked is not None:
+                    stats.join_ranked_builds += 1
+                    stats.join_rank_keys += len(b.ranked.keys)
                 btabs.append(btab)
             init_fn, pstep = _cached_join_probe_step(
                 self.spec, self.probe.meta, mesh, self.probe.batch_rows,
@@ -1361,7 +1445,9 @@ class DistributedJoinAgg:
                     self.spec, self.probe.meta, mesh,
                     self.probe.batch_rows, gather=self.probe.gather,
                     stats=stats)
-            edge_btabs = [btabs[bi] for bi, _ in self.spec.probe_edges]
+            edges = [bi for bi, _ in self.spec.probe_edges]
+            edge_btabs = [btabs[bi] for bi in edges] + [
+                keys[bi] for bi in edges if keys[bi] is not None]
             dense = _dense_reduce(self.pspec.n_groups)
             with span("loop", stats):
                 carry = devman.adopt(carry_key, init_fn(),
@@ -1736,8 +1822,11 @@ class ParallelExecutor(Executor):
         for k, src in zip(jspec.group_keys, jspec.group_sources):
             if src[0] == "key":
                 col = catalog.table(gb.table).column(gb.key)
-                v = (gids.astype(np.float64) + jspec.key_domain[0]) \
-                    .astype(col.data.dtype)
+                if gb.ranked is not None:
+                    v = gb.ranked.keys[gids].astype(col.data.dtype)
+                else:
+                    v = (gids.astype(np.float64) + jspec.key_domain[0]) \
+                        .astype(col.data.dtype)
             else:
                 col = catalog.table(gb.table).column(gb.payload[src[1]])
                 v = pay[:, src[1]].astype(col.data.dtype)

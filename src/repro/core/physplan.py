@@ -76,7 +76,8 @@ DEVICE_BATCH_ROWS = 1 << 16   # morsel batch streamed through the device
                               # so results are budget-invariant
 SUPPORTED_DEVICE_AGGS = {"count", "sum", "avg", "min", "max"}
 
-# device join tier: the dense build-table domain may exceed the scan-agg
+# device join tier: the build matrix has one row per distinct build key
+# (dense or ranked domain, see ``DeviceBuild``) and may exceed the scan-agg
 # group cap because the merged partial matrix never materializes on host —
 # device-resident assembly compacts it in HBM first.  Build keys must be
 # unique (verified at runtime; duplicates fall back to the host join).
@@ -202,25 +203,72 @@ def find_scan_agg_core(plan: PlanNode, catalog
 # ---------------------------------------------------------------------------
 
 
+def build_keys(table, column: str) -> np.ndarray:
+    """Sorted distinct non-NULL values of an integer column, computed once
+    per table version: they are cached on the table object, which every
+    write replaces."""
+    cache = table.__dict__.setdefault("_build_keys", {})
+    keys = cache.get(column)
+    if keys is None:
+        col = table.column(column)
+        v = np.asarray(col.data)
+        keys = cache[column] = np.unique(v[v != NULL_SENTINEL[col.dbtype]])
+    return keys
+
+
+@dataclass(eq=False)
+class RankedKeys:
+    """A sparse build key's ranked domain: its sorted distinct non-NULL
+    values, one build-matrix row each.  A key codes to its rank (its
+    position here) only when it equals the value stored there; ranks keep
+    key order."""
+    table: str
+    column: str
+    version: int
+    keys: np.ndarray
+
+    @property
+    def ident(self) -> tuple:
+        """What a compiled step depends on.  The keys themselves reach the
+        step as an argument, never as a constant."""
+        return ("ranked", self.table, self.column, self.version,
+                len(self.keys))
+
+
 @dataclass
 class DeviceBuild:
     """One build side of a device join: a filtered base scan whose unique
-    dense-domain key becomes the row index of a (card, 1 + n_payload)
-    scatter-add matrix in HBM.  Column 0 is the presence count (== 1 for a
-    unique key, verified at runtime); the payload columns are the build's
-    group-key contributions, recovered at assembly time by gathering the
-    matrix at the surviving key codes."""
+    key codes the row of a (card, 1 + n_payload) scatter-add matrix in
+    HBM.  A key whose distinct values fill their span has a dense domain
+    (a key's row is its distance from the smallest); a sparser key is
+    ranked (``ranked``; ``domain`` is then ``(0.0, distinct count)``).
+    Column 0 is the presence count (== 1 for a unique key, verified at
+    runtime); the payload columns are the build's group-key contributions,
+    recovered at assembly time by gathering the matrix at the surviving
+    key codes."""
     table: str
     conjuncts: list                      # filters on this table's columns
     key: str                             # build-side join key column
-    domain: tuple[float, int]            # (offset, cardinality), dense ints
+    domain: tuple[float, int]            # (offset, cardinality)
     payload: list                        # build columns consumed at assembly
     probe_edges: list                    # [(earlier build idx, local col)]
     columns: list                        # all referenced columns
+    ranked: Optional[RankedKeys] = None  # the ranked domain, if sparse
 
     @property
     def table_bytes(self) -> int:
         return self.domain[1] * (1 + len(self.payload)) * 8
+
+    @property
+    def state_bytes(self) -> int:
+        """The build matrix plus, for a ranked domain, its keys in HBM."""
+        ranks = 0 if self.ranked is None else self.ranked.keys.nbytes
+        return self.table_bytes + ranks
+
+    @property
+    def domain_key(self) -> tuple:
+        """The domain as a compiled step sees it."""
+        return self.domain if self.ranked is None else self.ranked.ident
 
 
 @dataclass
@@ -275,7 +323,7 @@ class JoinAggSpec:
     def state_bytes(self) -> int:
         k = len(partial_layout(self.probe_spec()).kinds)
         return self.n_groups * k * 8 \
-            + sum(b.table_bytes for b in self.builds)
+            + sum(b.state_bytes for b in self.builds)
 
 
 def _flatten_join_tree(node: PlanNode):
@@ -312,20 +360,12 @@ def _flatten_join_tree(node: PlanNode):
     return tables, edges, loose
 
 
-def _dense_int_domain(col) -> Optional[tuple[float, int]]:
-    v = np.asarray(col.data)
-    nn = v[v != NULL_SENTINEL[col.dbtype]]
-    if nn.size == 0:
-        return None
-    mn, mx = int(nn.min()), int(nn.max())
-    return float(mn), mx - mn + 1
-
-
 def match_join_agg(plan: PlanNode, catalog) -> Optional[JoinAggSpec]:
     """Aggregate( Filter* ( Join tree of filtered base scans ) ) where the
     join graph is a tree rooted at the probe table (the one the aggregate
-    expressions read), every build key has a dense integer domain, and all
-    group keys are functionally dependent on ONE probe-adjacent build."""
+    expressions read), every build key is an integer with at most
+    ``MAX_DEVICE_JOIN_DOMAIN`` distinct values, and all group keys are
+    functionally dependent on ONE probe-adjacent build."""
     if not isinstance(plan, AggregateNode):
         return None
     if any(a.fn not in SUPPORTED_DEVICE_AGGS for a in plan.aggs):
@@ -429,9 +469,15 @@ def match_join_agg(plan: PlanNode, catalog) -> Optional[JoinAggSpec]:
         col = cats[t].column(key)
         if col.dbtype not in DEVICE_JOIN_KEY_TYPES:
             return None
-        dom = _dense_int_domain(col)
-        if dom is None or dom[1] > MAX_DEVICE_JOIN_DOMAIN:
+        keys = build_keys(cats[t], key)
+        if keys.size == 0 or keys.size > MAX_DEVICE_JOIN_DOMAIN:
             return None
+        lo, span = int(keys[0]), int(keys[-1]) - int(keys[0]) + 1
+        if keys.size == span:
+            dom, ranked = (float(lo), span), None
+        else:
+            dom = (0.0, int(keys.size))
+            ranked = RankedKeys(t, key, cats[t].version, keys)
         payload = []
         if t == group_build:
             for g in plan.group_by:
@@ -447,7 +493,7 @@ def match_join_agg(plan: PlanNode, catalog) -> Optional[JoinAggSpec]:
         for conj in tables[t]:
             cols |= conj.columns()
         builds.append(DeviceBuild(
-            t, tables[t], key, dom, payload, pedges, sorted(cols)))
+            t, tables[t], key, dom, payload, pedges, sorted(cols), ranked))
 
     probe_edges = [(bidx[other], tcol)
                    for other, okey, tcol in adj[probe] if other in bidx]
@@ -576,15 +622,15 @@ def scan_agg_geometry(spec: ScanAggSpec, table, shards: int,
 class JoinAggGeometry:
     """Batch decomposition + byte footprint of one device join-agg.  The
     probe fields quack like ``ScanAggGeometry``; ``state_bytes`` is the
-    HBM-resident working state (build matrices + carry) that stays on
-    device for the whole query, and ``working_bytes`` is the streamed
-    admission unit: state plus a double-buffered copy of the largest
-    single stream batch (build or probe)."""
+    HBM-resident working state (build matrices, ranked keys, carry) that
+    stays on device for the whole query, and ``working_bytes`` is the
+    streamed admission unit: state plus a double-buffered copy of the
+    largest single stream batch (build or probe)."""
     batch_rows: int              # probe batch rows
     n_batches: int               # probe batch count
     row_bytes: int               # probe bytes per row
     carry_nbytes: int            # probe partial-matrix bytes
-    state_bytes: int             # carry + all build matrices
+    state_bytes: int             # carry + build matrices + ranked keys
     max_batch_bytes: int         # largest single batch across all streams
     working_bytes: int           # state + 2 * max batch (streamed unit)
     resident_bytes: int          # every stream fully resident + state
@@ -595,7 +641,7 @@ def join_agg_geometry(spec: JoinAggSpec, catalog, shards: int,
                       batch_rows: Optional[int] = None) -> JoinAggGeometry:
     pg = scan_agg_geometry(spec.probe_spec(), catalog.table(spec.probe_table),
                            shards, batch_rows)
-    state = pg.carry_nbytes + sum(b.table_bytes for b in spec.builds)
+    state = pg.carry_nbytes + sum(b.state_bytes for b in spec.builds)
     max_batch = pg.batch_rows * pg.row_bytes
     resident = pg.n_batches * pg.batch_rows * pg.row_bytes
     build_geoms = []
@@ -1293,8 +1339,16 @@ class PhysicalPlan:
             detail = f"groups={self.scan_agg.n_groups}"
             detail += f" batches={g.n_batches}x{g.batch_rows}rows"
 
+        ranked = {} if self.join_agg is None else {
+            b.key: len(b.ranked.keys) for b in self.join_agg.builds
+            if b.ranked is not None}
+
         def fused(n: PlanNode) -> PhysicalOp:
             d = "(fused)"
+            if isinstance(n, JoinNode):
+                for k in (*n.left_keys, *n.right_keys):
+                    if k in ranked:
+                        d = f"{d} ranked keys: {ranked[k]}"
             note = self._skip_note(n)
             if note:
                 d = f"{d} {note}"

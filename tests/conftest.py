@@ -2,6 +2,9 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the benchmark's generator and query references (``bench``) are tests'
+# reference data too
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 import pytest

@@ -26,7 +26,10 @@ import os
 import numpy as np
 import pytest
 
-from repro.core import Col, startup
+from bench.datagen import tpch as spec_gen
+from bench.queries import q3 as q3_ref
+from bench.run import load
+from repro.core import Col, physplan, startup
 from repro.core.physplan import (TIER_DEVICE_RESIDENT, TIER_DEVICE_STREAMED,
                                  TIER_IN_MEMORY, TIER_SPILL,
                                  choose_device_tier, find_scan_agg_core,
@@ -440,3 +443,101 @@ def test_golden_physical_plan(qname):
     with open(path) as f:
         want = f.read().rstrip("\n")
     assert got == want, f"golden physical plan drifted for {qname}:\n{got}"
+
+
+# ---------------------------------------------------------------------------
+# device join build domains: dense offset or ranked keys
+# ---------------------------------------------------------------------------
+
+
+def _spec_q3_db(**kw):
+    """Q3's tables at 1% of SF 1 from the benchmark's generator: the
+    specification's sparse order keys (a ranked orders build) and dense
+    customer keys (an offset customer build)."""
+    data = spec_gen.generate(0.01, 2**31 + 19, list(q3_ref.COLUMNS))
+    data = {t: {c: data[t][c] for c in cols}
+            for t, cols in q3_ref.COLUMNS.items()}
+    db = startup(**kw)
+    load(db, data)
+    return db, data
+
+
+@pytest.mark.parametrize("over", [False, True])
+def test_device_join_cap_counts_distinct_build_keys(monkeypatch, over):
+    """``MAX_DEVICE_JOIN_DOMAIN`` bounds the rows of a build matrix: a
+    ranked domain's distinct keys, not their span.  At the cap the sparse
+    orders build (span four times its keys) runs on the device; one key
+    over it, Q3 plans on the host.  Either way the answer is the host
+    executor's and the numpy reference's."""
+    db, data = _spec_q3_db(device_budget=64 << 20, device_batch_rows=8192)
+    host = startup()
+    load(host, data)
+    keys = data["orders"]["o_orderkey"].data
+    n = keys.size
+    assert keys.max() - keys.min() + 1 > 3 * n
+    monkeypatch.setattr(physplan, "MAX_DEVICE_JOIN_DOMAIN",
+                        n - 1 if over else n)
+    try:
+        q = db.sql(q3_ref.SQL)
+        txt = q.explain(physical=True, distributed=True)
+        got = q.execute(distributed=True).to_pydict()
+        st = db.last_stats
+        want = host.sql(q3_ref.SQL).execute().to_pydict()
+    finally:
+        db.shutdown()
+        host.shutdown()
+    if over:
+        assert ":: device-join" not in txt and st.device_tier == ""
+    else:
+        assert f"ranked keys: {n}" in txt
+        assert st.device_tier == "join-resident"
+    assert list(got) == list(want)
+    for c in got:
+        np.testing.assert_allclose(np.asarray(got[c], float),
+                                   np.asarray(want[c], float), rtol=1e-9,
+                                   err_msg=c)
+    checks = q3_ref.compare(got, q3_ref.reference(data))
+    assert checks["q3_mismatch"] == 0, checks
+    assert checks["q3_rel_err"] <= q3_ref.LIMITS["q3_rel_err"], checks
+
+
+@pytest.mark.parametrize("layout", ["dense", "sparse"])
+def test_build_domains_dense_offset_or_ranked(layout):
+    """A build key whose distinct values fill their span keeps the dense
+    offset domain: no ranked keys, no ``ranked keys`` in EXPLAIN, no rank
+    span, both rank counters 0.  Sparse order keys are ranked: the span
+    and counters read the orders build."""
+    if layout == "dense":
+        db = startup(device_budget=64 << 20, device_batch_rows=4096)
+        tpch.load_into(db, sf=SF, seed=3,
+                       tables=["customer", "orders", "lineitem"])
+        q = ALL_QUERIES["q3"](db)
+    else:
+        db, _data = _spec_q3_db(device_budget=64 << 20,
+                                device_batch_rows=4096)
+        q = db.sql(q3_ref.SQL)
+    try:
+        builds = {b.table: b for b in plan_physical(
+            q.plan, db, distributed=True).join_agg.builds}
+        txt = q.explain(physical=True, distributed=True)
+        q.execute(distributed=True)
+        st = db.last_stats
+        n_orders = db.catalog.table("orders").num_rows
+    finally:
+        db.shutdown()
+    cust, orders = builds["customer"], builds["orders"]
+    assert cust.ranked is None and cust.domain_key == cust.domain
+    assert orders.domain == (0.0, n_orders)
+    assert st.device_tier.startswith("join-") and not st.device_fallback
+    if layout == "dense":
+        assert orders.ranked is None
+        assert "ranked keys" not in txt
+        assert (st.join_ranked_builds, st.join_rank_keys) == (0, 0)
+        assert "rank" not in st.span_n
+    else:
+        assert len(orders.ranked.keys) == n_orders
+        assert orders.domain_key == ("ranked", "orders", "o_orderkey",
+                                     orders.ranked.version, n_orders)
+        assert f"ranked keys: {n_orders}" in txt
+        assert (st.join_ranked_builds, st.join_rank_keys) == (1, n_orders)
+        assert st.span_n["rank"] == 1

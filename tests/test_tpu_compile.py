@@ -3,9 +3,9 @@
 Each test lowers one step of the served path for a described (not
 attached) v5e chip at the shapes TPC-H SF 1 gives it: 65,536-row batches
 and SF 1 key domains, with the specs the physical planner derives for
-Q1, Q6 and Q3.  What the chip's compiler refuses here fails without
-spending chip time.  Nothing runs, so these tests say nothing about
-results or speed.
+Q1, Q6 and Q3 (Q3 on dense and on ranked order keys).  What the chip's
+compiler refuses here fails without spending chip time.  Nothing runs,
+so these tests say nothing about results or speed.
 """
 
 import dataclasses
@@ -16,7 +16,7 @@ import pytest
 
 from repro.core import parallel as par
 from repro.core import startup
-from repro.core.physplan import DEVICE_BATCH_ROWS, plan_physical
+from repro.core.physplan import DEVICE_BATCH_ROWS, RankedKeys, plan_physical
 from repro.data import tpch
 from repro.data.tpch_queries import ALL_QUERIES
 
@@ -91,14 +91,32 @@ def _matrix(mesh, rows, width):
                                 sharding=NamedSharding(mesh, P()))
 
 
-def _q3_sf1(db):
-    """Q3's join spec with SF 1 key domains (dense keys from 0)."""
+def _q3_sf1(db, ranked=False):
+    """Q3's join spec with SF 1 key domains: dense keys from 0, or with
+    ``ranked`` the orders build ranked over the specification's sparse
+    order keys (the first 8 of every 32)."""
     js = _phys(db, "q3").join_agg
-    builds = [dataclasses.replace(b, domain=(0.0, tpch.SF_ROWS[b.table]))
-              for b in js.builds]
+    builds = []
+    for b in js.builds:
+        n = tpch.SF_ROWS[b.table]
+        b = dataclasses.replace(b, domain=(0.0, n))
+        if ranked and b.table == "orders":
+            i = np.arange(n, dtype=np.int64)
+            b = dataclasses.replace(b, ranked=RankedKeys(
+                "orders", b.key, 0, (i // 8) * 32 + i % 8 + 1))
+        builds.append(b)
     n = tpch.SF_ROWS["orders"]
     return dataclasses.replace(js, builds=builds, key_domain=(0.0, n),
                                n_groups=n)
+
+
+def _keys(mesh, builds):
+    """Shapes of the sorted keys of each ranked build, replicated."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return [jax.ShapeDtypeStruct(b.ranked.keys.shape, b.ranked.keys.dtype,
+                                 sharding=NamedSharding(mesh, P()))
+            for b in builds if b.ranked is not None]
 
 
 @pytest.mark.parametrize("q", ["q1", "q6"])
@@ -112,31 +130,50 @@ def test_scan_agg_batch_step_compiles(one_chip, small_db, q):
                               spec.columns)).compile()
 
 
-def test_q3_join_build_step_compiles(one_chip, small_db):
-    """The orders build at SF 1: a (1.5M, 3) matrix probed through the
-    customer build's (150k, 1) presence matrix."""
-    js = _q3_sf1(small_db)
+def _compile_build(db, mesh, ranked):
+    js = _q3_sf1(db, ranked)
     orders = next(b for b in js.builds if b.table == "orders")
     children = [js.builds[ci] for ci, _ in orders.probe_edges]
     _init, step = par.build_join_build_step(
-        orders, _meta(small_db, "orders", orders.columns), one_chip,
-        tuple(c.domain for c in children))
-    btab = _matrix(one_chip, orders.domain[1], 1 + len(orders.payload))
-    kids = [_matrix(one_chip, c.domain[1], 1 + len(c.payload))
+        orders, _meta(db, "orders", orders.columns), mesh, children)
+    btab = _matrix(mesh, orders.domain[1], 1 + len(orders.payload))
+    kids = [_matrix(mesh, c.domain[1], 1 + len(c.payload))
             for c in children]
-    step.lower(btab, *kids, *_batch(small_db, one_chip, "orders",
-                                    orders.columns)).compile()
+    step.lower(btab, *kids, *_keys(mesh, [orders, *children]),
+               *_batch(db, mesh, "orders", orders.columns)).compile()
+
+
+def _compile_probe(db, mesh, ranked):
+    js = _q3_sf1(db, ranked)
+    pspec = js.probe_spec()
+    _init, step = par.build_join_probe_step(
+        js, _meta(db, pspec.table, pspec.columns), mesh)
+    carry = _matrix(mesh, pspec.n_groups,
+                    len(par.partial_layout(pspec).kinds))
+    builds = [js.builds[bi] for bi, _ in js.probe_edges]
+    edges = [_matrix(mesh, b.domain[1], 1 + len(b.payload))
+             for b in builds]
+    step.lower(carry, *edges, *_keys(mesh, builds),
+               *_batch(db, mesh, pspec.table, pspec.columns)).compile()
+
+
+def test_q3_join_build_step_compiles(one_chip, small_db):
+    """The orders build at SF 1: a (1.5M, 3) matrix probed through the
+    customer build's (150k, 1) presence matrix."""
+    _compile_build(small_db, one_chip, ranked=False)
 
 
 def test_q3_join_probe_step_compiles(one_chip, small_db):
-    js = _q3_sf1(small_db)
-    pspec = js.probe_spec()
-    _init, step = par.build_join_probe_step(
-        js, _meta(small_db, pspec.table, pspec.columns), one_chip)
-    carry = _matrix(one_chip, pspec.n_groups,
-                    len(par.partial_layout(pspec).kinds))
-    edges = [_matrix(one_chip, js.builds[bi].domain[1],
-                     1 + len(js.builds[bi].payload))
-             for bi, _ in js.probe_edges]
-    step.lower(carry, *edges, *_batch(small_db, one_chip, pspec.table,
-                                      pspec.columns)).compile()
+    _compile_probe(small_db, one_chip, ranked=False)
+
+
+def test_q3_ranked_join_build_step_compiles(one_chip, small_db):
+    """The orders build at SF 1 on the specification's sparse keys: each
+    batch's keys are ranked against the 1.5M sorted keys, an argument."""
+    _compile_build(small_db, one_chip, ranked=True)
+
+
+def test_q3_ranked_join_probe_step_compiles(one_chip, small_db):
+    """The probe at SF 1 whose gid is the lineitem key's rank among the
+    1.5M sorted order keys: 1.5M groups, so the scatter merges them."""
+    _compile_probe(small_db, one_chip, ranked=True)
